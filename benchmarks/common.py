@@ -12,6 +12,7 @@ import jax
 
 from repro.core import paa
 from repro.graph.generators import alibaba_like
+from repro.kernels.frontier.frontier import resolve_interpret
 
 # free-form provenance note threaded through `benchmarks.run --platform`
 # (e.g. "ci-cpu-skylake", "v5p-8 pod slice"); lands in every BENCH json
@@ -25,14 +26,17 @@ def set_platform_note(note: str | None) -> None:
 
 def bench_env() -> dict:
     """The stable env header of every ``BENCH_*.json``: which XLA
-    backend actually executed, whether the Pallas kernels ran in
-    interpret mode (off-TPU they always do — those latencies are
-    interpreter numbers, not kernel numbers), and the operator-supplied
-    platform note."""
-    backend = jax.default_backend()
+    backend and device actually executed, how many devices, whether the
+    Pallas kernels ran in interpret mode (the flag the kernels resolve,
+    :func:`repro.kernels.frontier.frontier.resolve_interpret`; off-TPU
+    it is always on — those latencies are interpreter numbers, not
+    kernel numbers), and the operator-supplied platform note."""
+    devices = jax.devices()
     return {
-        "jax_backend": backend,
-        "interpret": backend != "tpu",
+        "jax_backend": jax.default_backend(),
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "interpret": resolve_interpret(),
         "platform_note": PLATFORM_NOTE,
     }
 

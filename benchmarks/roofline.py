@@ -89,7 +89,7 @@ def run_packed(
     chunk_edges: int = 50_000,
     out: str = PACKED_JSON,
     seed: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
     budget_bytes: int | None = None,
 ) -> list[str]:
     import numpy as np
@@ -102,6 +102,9 @@ def run_packed(
     from repro.core.plans import GraphPlanStore
     from repro.graph.generators import random_labeled_graph
     from repro.kernels.frontier import ops as fops
+    from repro.kernels.frontier.frontier import resolve_interpret
+
+    interpret = resolve_interpret(interpret)
 
     g = random_labeled_graph(n_nodes, n_edges, n_labels, seed=seed)
     bg = fops.make_blocked_graph(g, block_size=block)

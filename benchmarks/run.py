@@ -154,6 +154,8 @@ def main() -> None:
             except FileNotFoundError:
                 ap.error(f"--regress needs a checked-in {path} baseline")
 
+    from repro import compile_cache
+
     from benchmarks import (
         common,
         fig2_costs,
@@ -172,6 +174,7 @@ def main() -> None:
     )
 
     common.set_platform_note(args.platform)
+    compile_cache.enable()
 
     modules = [
         ("table1", table1_complexity),
@@ -192,6 +195,7 @@ def main() -> None:
         ("witness", witness),
     ]
 
+    errored: list[str] = []
     for name, mod in modules:
         if name not in selected:
             continue
@@ -200,9 +204,10 @@ def main() -> None:
         try:
             for row in mod.run():
                 print(row)
-        except Exception:  # noqa: BLE001 — keep the sweep going
+        except Exception:  # noqa: BLE001 — finish the sweep, then fail it
             traceback.print_exc()
             print(f"{name},ERROR")
+            errored.append(name)
         print(f"# {name} took {time.time() - t0:.1f}s", flush=True)
 
     if baselines:
@@ -227,6 +232,9 @@ def main() -> None:
             )
             sys.exit(1)
         print(f"regress,OK,every gated latency metric within {REGRESS_FACTOR}x of baseline")
+    if errored:
+        print(f"# FAILED subsets: {','.join(errored)}", flush=True)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import bench_env
 from repro.core import paa, planner
+from repro.kernels.frontier.frontier import resolve_interpret
 from repro.kernels.frontier.ops import (
     QPAD,
     build_level_plan,
@@ -78,8 +79,9 @@ def run(
     repeats: int = 5,
     out: str = "BENCH_witness.json",
     seed: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> list[str]:
+    interpret = resolve_interpret(interpret)
     g = random_labeled_graph(n_nodes, n_edges, n_labels, seed=seed)
     bg = make_blocked_graph(g, block_size=block)
     rng = np.random.default_rng(seed)

@@ -18,6 +18,7 @@ import numpy as np
 
 import jax
 
+from repro import compile_cache
 from repro.core import paa, planner
 from repro.dist import compat
 from repro.graph import generators
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--small", action="store_true", help="40k-edge twin (fast)")
     ap.add_argument("--queries", default="q1,q2,q6,q11")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.small:
         g = generators.alibaba_like(n_nodes=8000, n_edges=40000, seed=0)

@@ -54,7 +54,7 @@ from typing import Any, Callable, Hashable
 
 import numpy as np
 
-import jax.numpy as jnp
+import jax
 
 from repro.core.automaton import FWD, INV
 from repro.graph.partition import Placement
@@ -394,17 +394,19 @@ class GraphPlanStore:
         epoch: int = 0,
         floor: int = fops.BUCKET_FLOOR,
         tile_dtype: str = "f32",
+        sharding: Any = None,
     ) -> fops.ShardedTileBuckets:
         """The sharded fused backend's Stage-A shape buckets: the
         device-granular merged slabs grouped into power-of-two tile
-        classes and stacked on device per bucket.  Keyed by (placement,
-        axis_size, floor) on top of the staging key — the bucket layout
-        depends on how sites block over the mesh's site axes, but not on
-        the automaton.  The resulting ``bucket_id`` joins the executor
-        cache's graph key."""
+        classes and stacked on device per bucket, placed by ``sharding``
+        (see :func:`repro.kernels.frontier.ops.bucket_staged_sites`).
+        Keyed by (placement, axis_size, floor, sharding) on top of the
+        staging key — the bucket layout depends on how sites block over
+        the mesh's site axes, but not on the automaton.  The resulting
+        ``bucket_id`` joins the executor cache's graph key."""
         key = (
             "tile_buckets", id(placement), epoch, block_size, axis_size, floor,
-            tile_dtype,
+            tile_dtype, sharding,
         )
         return self._get(
             key,
@@ -414,21 +416,25 @@ class GraphPlanStore:
                 self.staged_merged(placement, block_size, axis_size, epoch, tile_dtype),
                 axis_size,
                 floor,
+                sharding,
             ),
         )
 
     def site_device_arrays(
-        self, placement: Placement, epoch: int = 0
-    ) -> dict[str, jnp.ndarray]:
+        self, placement: Placement, epoch: int = 0, sharding: Any = None
+    ) -> dict[str, jax.Array]:
         """The placement's padded per-site edge arrays, staged on device
-        (the ``reference`` S2 executor's and S1's gather operands)."""
-        key = ("site_arrays", id(placement), epoch)
+        (the ``reference`` S2 executor's and S1's gather operands) and
+        placed by ``sharding`` — split over the mesh's site axes, each
+        device holds its own sites' rows (default: the default device)."""
+        key = ("site_arrays", id(placement), epoch, sharding)
         return self._get(
             key,
             placement,
             epoch,
             lambda: {
-                k: jnp.asarray(v) for k, v in placement.padded_device_arrays().items()
+                k: jax.device_put(v, sharding)
+                for k, v in placement.padded_device_arrays().items()
             },
         )
 

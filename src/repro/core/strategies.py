@@ -17,8 +17,7 @@ Strategies:
   its degenerate bound — modeled analytically from placement statistics.
 
 Execution vs accounting (DESIGN.md §2): the *executors* run S1/S2 with
-real mesh collectives via ``repro.dist.sharding.shard_map`` (sites = the
-``data`` axis;
+real mesh collectives via ``jax.shard_map`` (sites = the ``data`` axis;
 the query batch = the ``model`` axis); the *meters* count message symbols
 with the paper's cost conventions (a symbol = one node id or label; an
 edge = 3 symbols; broadcasting b symbols costs 2·N_c·b messages).
@@ -44,7 +43,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import paa
-from repro.dist import sharding as shd
 from repro.core.automaton import FWD, CompiledAutomaton
 from repro.core.regex import Node, has_wildcard, labels_of, query_size
 from repro.core.witness import INF_LEVEL
@@ -241,7 +239,7 @@ def s1_gather(
         return src, lbl, dst, match, overflow.sum()[None]
 
     spec_e = P(site_axes, None)
-    fn = shd.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_e, spec_e, spec_e, spec_e, P()),
@@ -403,6 +401,15 @@ def symbol_set_groups(
     return tuple(
         sorted((symset, tuple(sorted(states))) for symset, states in groups.items())
     )
+
+
+def site_sharding(mesh: Mesh, site_axes: tuple[str, ...] = ("data",)) -> NamedSharding:
+    """Placement of per-site stacks (leading dim = sites, device-major):
+    split over the mesh's site axes, so each device holds exactly the
+    sites it runs — the site edge arrays and the sharded backend's tile
+    buckets are staged this way once, instead of landing on the default
+    device and moving on every call."""
+    return NamedSharding(mesh, P(tuple(site_axes)))
 
 
 def make_s2_step_fn(
@@ -663,8 +670,6 @@ def make_s2_step_fn(
 
     spec_e = P(site_axes, None)
     spec_b = P(batch_axis) if batch_axis else P()
-    # check_vma=False is required: JAX 0.4.x has no replication rule for
-    # the BFS while_loop (NotImplementedError under check_rep=True)
     out_b = P(batch_axis) if batch_axis else P()
     out_specs = (
         P(batch_axis, None) if batch_axis else P(None, None),
@@ -677,7 +682,7 @@ def make_s2_step_fn(
             P(batch_axis, None, None) if batch_axis else P(None, None, None),
         )
     return jax.jit(
-        shd.shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(spec_e, spec_e, spec_e, spec_e, spec_b),
@@ -765,8 +770,7 @@ def _make_frontier_step_fn(
         )
     if graph.n_nodes != n_nodes:
         raise ValueError(f"graph has {graph.n_nodes} nodes, executor built for {n_nodes}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = fkernel.resolve_interpret(interpret)
     staged = _fetch_staged_graph(
         ca, graph, block_size, plan_store, stats_epoch, tile_dtype,
         tile_store_budget_bytes,
@@ -786,11 +790,11 @@ def _make_frontier_step_fn(
         else None
     )
     deg, payloads = _site_symbol_degrees(sgroups, [graph], v_pad, label_deg)
-    deg_c = jnp.asarray(deg[0])
-    pay_c = jnp.asarray(payloads)
+    operands = (*_schedule_operands(plan), jnp.asarray(deg[0]), jnp.asarray(payloads))
     state_rows = [jnp.asarray(states, jnp.int32) for _, states in sgroups]
 
-    def fixpoint(f0):  # (n_states, q_pad, v_pad) f32 0/1
+    def fixpoint(f0, operands):  # (n_states, q_pad, v_pad) f32 0/1
+        sched, deg_c, pay_c = operands[:8], operands[8], operands[9]
         flat0 = f0.reshape(n_states * q_pad, v_pad)
         zero_q = jnp.zeros((q_pad,), jnp.float32)
 
@@ -815,9 +819,7 @@ def _make_frontier_step_fn(
                 frontier, plan.union_members, n_states, q_pad
             )
             counts = fkernel.fused_level_blocks(
-                fre, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-                plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
-                plan.block_size, q_pad, interpret=interpret,
+                fre, *sched, plan.block_size, q_pad, interpret=interpret,
                 n_out_rows=n_states * q_pad,
             )
             nxt = jnp.minimum(counts, 1.0)
@@ -850,8 +852,7 @@ def _make_frontier_step_fn(
             out = out + (levmap.transpose(1, 0, 2)[:, :, :n_nodes],)
         return out
 
-    def fn(src, lbl, dst, mask, starts):
-        del src, lbl, dst, mask  # retrieval is modeled on the staged global tiles
+    def run(operands, starts):
         b = starts.shape[0]
         n_chunks = -(-b // q_pad)
         pad = n_chunks * q_pad - b
@@ -865,7 +866,7 @@ def _make_frontier_step_fn(
                 .at[ca.start, jnp.arange(q_pad), schunk]
                 .set(1.0)
             )
-            return fixpoint(f0)
+            return fixpoint(f0, operands)
 
         out = jax.lax.map(one_chunk, chunks)
         acc, q_bc, d_s2, n_bc = out[:4]
@@ -881,7 +882,8 @@ def _make_frontier_step_fn(
             )
         return res
 
-    return jax.jit(fn)
+    # retrieval is modeled on the staged global tiles
+    return _bind_operands(run, operands, interpret)
 
 
 def _make_frontier_packed_step_fn(
@@ -932,8 +934,7 @@ def _make_frontier_packed_step_fn(
         )
     if graph.n_nodes != n_nodes:
         raise ValueError(f"graph has {graph.n_nodes} nodes, executor built for {n_nodes}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = fkernel.resolve_interpret(interpret)
     staged = _fetch_staged_graph(
         ca, graph, block_size, plan_store, stats_epoch, tile_dtype,
         tile_store_budget_bytes,
@@ -952,8 +953,7 @@ def _make_frontier_packed_step_fn(
         else None
     )
     deg, payloads = _site_symbol_degrees(sgroups, [graph], v_pad, label_deg)
-    deg_c = jnp.asarray(deg[0])
-    pay_c = jnp.asarray(payloads)
+    operands = (*_schedule_operands(plan), jnp.asarray(deg[0]), jnp.asarray(payloads))
     state_rows = [jnp.asarray(states, jnp.int32) for _, states in sgroups]
     bit_shifts = jnp.arange(32, dtype=jnp.uint32)
 
@@ -968,7 +968,8 @@ def _make_frontier_packed_step_fn(
         ) != 0
         return bits.reshape(n_states, q_pack, v_pad)
 
-    def fixpoint(f0):  # (n_states, q_pad, v_pad) uint32 lane words
+    def fixpoint(f0, operands):  # (n_states, q_pad, v_pad) uint32 lane words
+        sched, deg_c, pay_c = operands[:8], operands[8], operands[9]
         flat0 = f0.reshape(n_states * q_pad, v_pad)
         zero_q = jnp.zeros((q_pack,), jnp.float32)
 
@@ -996,9 +997,7 @@ def _make_frontier_packed_step_fn(
                 frontier, plan.union_members, n_states, q_pad
             )
             nxt = fkernel.packed_level_blocks(
-                fre, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-                plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
-                plan.block_size, q_pad, interpret=interpret,
+                fre, *sched, plan.block_size, q_pad, interpret=interpret,
                 n_out_rows=n_states * q_pad,
             )
             new = nxt & ~visited
@@ -1035,8 +1034,7 @@ def _make_frontier_packed_step_fn(
 
     lane_ids = jnp.arange(q_pack, dtype=jnp.int32)
 
-    def fn(src, lbl, dst, mask, starts):
-        del src, lbl, dst, mask  # retrieval is modeled on the staged global tiles
+    def run(operands, starts):
         b = starts.shape[0]
         n_chunks = -(-b // q_pack)
         pad = n_chunks * q_pack - b
@@ -1052,7 +1050,7 @@ def _make_frontier_packed_step_fn(
                 .at[ca.start, lane_ids // 32, schunk]
                 .add(jnp.uint32(1) << (lane_ids % 32).astype(jnp.uint32))
             )
-            return fixpoint(f0)
+            return fixpoint(f0, operands)
 
         out = jax.lax.map(one_chunk, chunks)
         acc, q_bc, d_s2, n_bc = out[:4]
@@ -1068,7 +1066,40 @@ def _make_frontier_packed_step_fn(
             )
         return res
 
-    return jax.jit(fn)
+    # retrieval is modeled on the staged global tiles
+    return _bind_operands(run, operands, interpret)
+
+
+def _schedule_operands(plan) -> tuple:
+    """A fused plan's tile store and Stage-B step arrays, in the order
+    :func:`repro.kernels.frontier.frontier.fused_level_blocks` takes
+    them (the same order for ``packed_level_blocks``)."""
+    return (
+        plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
+        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+    )
+
+
+def _bind_operands(run, operands, interpret: bool):
+    """Wrap ``run(operands, starts)`` in the shared step contract
+    ``fn(src, lbl, dst, mask, starts)`` of the fused executors; they
+    ignore the site edge arrays and read only their staged operands.
+
+    The staged device arrays (tile store, Stage-B schedule, meter
+    vectors) are passed to the jitted program as arguments: an array
+    the program closed over would be embedded in it as a constant, so
+    every executor would compile, cache and hold its own copy of the
+    tile store.  ``fn.interpret`` records the resolved Pallas mode, and
+    ``fn.clear_cache`` drops the compiled programs on eviction."""
+    jitted = jax.jit(run)
+
+    def fn(src, lbl, dst, mask, starts):
+        del src, lbl, dst, mask
+        return jitted(operands, starts)
+
+    fn.clear_cache = jitted.clear_cache
+    fn.interpret = interpret
+    return fn
 
 
 def _site_symbol_degrees(
@@ -1212,10 +1243,10 @@ def _make_frontier_sharded_step_fn(
             f"n_sites={placement.n_sites} must be divisible by the site-axis "
             f"size {axis_size} (sites are blocked over {site_axes})"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = fkernel.resolve_interpret(interpret)
     if bucket_floor is None:
         bucket_floor = fops.BUCKET_FLOOR
+    stack_sharding = site_sharding(mesh, site_axes)
     if plan_store is not None:
         site_graphs = plan_store.local_graphs(placement, epoch=stats_epoch)
         exec_staged = plan_store.staged_merged(
@@ -1223,13 +1254,15 @@ def _make_frontier_sharded_step_fn(
         )
         tile_buckets = plan_store.tile_buckets(
             placement, block_size, axis_size, epoch=stats_epoch, floor=bucket_floor,
-            tile_dtype=tile_dtype,
+            tile_dtype=tile_dtype, sharding=stack_sharding,
         )
     else:
         site_graphs = [placement.local_graph(s) for s in range(placement.n_sites)]
         staged = fops.stage_sharded_graph(site_graphs, block_size, tile_dtype)
         exec_staged = fops.merge_staged_sites(staged, axis_size)
-        tile_buckets = fops.bucket_staged_sites(exec_staged, axis_size, bucket_floor)
+        tile_buckets = fops.bucket_staged_sites(
+            exec_staged, axis_size, bucket_floor, stack_sharding
+        )
     plan = fops.build_sharded_level_schedule(
         ca, exec_staged, tile_buckets, axis_size=axis_size, bucket_floor=bucket_floor
     )
@@ -1254,7 +1287,6 @@ def _make_frontier_sharded_step_fn(
         else None
     )
     deg, payloads = _site_symbol_degrees(sgroups, site_graphs, v_pad, label_deg)
-    deg_c = jnp.asarray(deg)
     pay_c = jnp.asarray(payloads)
     state_rows = [jnp.asarray(states, jnp.int32) for _, states in sgroups]
 
@@ -1437,24 +1469,30 @@ def _make_frontier_sharded_step_fn(
         out_specs = out_specs + (
             P(b_ax, None, None) if b_ax else P(None, None, None),
         )
-    sharded = shd.shard_map(
+    operand_specs = (*bucket_specs, spec_s(2))  # + deg (n_sites, n_groups, v_pad)
+    sharded = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
-            *bucket_specs,
-            spec_s(2),  # deg (n_sites, n_groups, v_pad)
+            *operand_specs,
             spec_b,  # starts: sharded over the batch axis, every site sees
             # its batch shard's full frontier (the broadcast half)
         ),
         out_specs=out_specs,
         check_vma=False,
     )
+    # each site's slab and schedule live on the device that runs its
+    # site, so a call moves only the start batch
+    operands = jax.device_put(
+        (*bucket_args, deg),
+        tuple(NamedSharding(mesh, spec) for spec in operand_specs),
+    )
 
-    def fn(src, lbl, dst, mask, starts):
-        del src, lbl, dst, mask  # retrieval runs on the staged per-site tiles
-        return sharded(*bucket_args, deg_c, starts)
+    def run(operands, starts):
+        return sharded(*operands, starts)
 
-    return jax.jit(fn)
+    # retrieval runs on the staged per-site tiles
+    return _bind_operands(run, operands, interpret)
 
 
 def s2_execute(
@@ -1525,7 +1563,9 @@ def s2_execute(
             for k in ("src", "lbl", "dst", "mask")
         }
     elif plan_store is not None:
-        arrays = plan_store.site_device_arrays(placement, epoch=stats_epoch)
+        arrays = plan_store.site_device_arrays(
+            placement, epoch=stats_epoch, sharding=site_sharding(mesh, site_axes)
+        )
     else:
         arrays = placement.padded_device_arrays()
     if step_fn is None:

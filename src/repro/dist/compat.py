@@ -1,106 +1,28 @@
-"""JAX version-drift shims (supported range: 0.4.35 – 0.7.x).
+"""Thin helpers over the installed JAX's mesh and compiled-program APIs.
 
-Every spot where the public JAX API moved between the 0.4 line and the
-0.5+/0.6+ lines is papered over here behind a stable helper, so the rest
-of the codebase is written once against the *new* spellings:
-
-* ``AxisType`` / ``make_mesh(axis_types=...)`` — ``jax.sharding.AxisType``
-  and the ``axis_types`` kwarg only exist on newer JAX; on 0.4.x meshes
-  are implicitly "auto" and the kwarg must not be passed.
-* ``shard_map`` — ``jax.shard_map(check_vma=...)`` on new JAX vs
-  ``jax.experimental.shard_map.shard_map(check_rep=...)`` on 0.4.x.
-* ``Compiled.cost_analysis()`` — returns a *list* of per-computation dicts
-  on 0.4.x and a plain dict on newer JAX.
+* ``make_mesh`` — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+  unless the caller says otherwise (the GSPMD behaviour the executors'
+  ``shard_map`` specs are written against).
+* ``cost_analysis_dict`` — ``Compiled.cost_analysis()`` as a plain dict,
+  empty on backends without a cost model.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-)
-
-# --------------------------------------------------------------------------
-# AxisType / make_mesh
-# --------------------------------------------------------------------------
-
-try:  # JAX >= 0.5
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_AXIS_TYPES = True
-except ImportError:  # JAX 0.4.x: meshes are implicitly Auto
-
-    class AxisType:  # minimal stand-in so call sites can always name it
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    HAS_AXIS_TYPES = False
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that works on every supported JAX.
-
-    On new JAX the mesh is built with explicit ``axis_types`` (defaulting
-    to all-Auto, the GSPMD behaviour the 0.4 line has implicitly); on
-    0.4.x the kwarg is dropped.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES and "axis_types" in _MAKE_MESH_PARAMS:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(tuple(axis_names))
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-# --------------------------------------------------------------------------
-# shard_map
-# --------------------------------------------------------------------------
-
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the new keyword spelling on every JAX.
-
-    ``check_vma`` (new name) maps onto ``check_rep`` (old name).  It
-    defaults to False because 0.4.x's replication checker lacks rules for
-    ops the executors rely on (e.g. ``while_loop``); call sites that can
-    bear the check pass ``check_vma=True`` explicitly.
-    """
-    if _NEW_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    return _old_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    """``jax.make_mesh`` with all-``Auto`` axis types by default."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names), axis_types=axis_types, devices=devices
     )
 
 
-# --------------------------------------------------------------------------
-# Compiled-artifact introspection
-# --------------------------------------------------------------------------
-
-
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every JAX.
-
-    0.4.x returns ``[{...}]`` (one dict per computation, entry 0 is the
-    main program); newer JAX returns the dict directly; either may be
-    empty/None on backends without cost models.
-    """
-    ca = compiled.cost_analysis()
-    if not ca:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca[0] else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a flat dict (empty when the
+    backend reports no cost model)."""
+    return dict(compiled.cost_analysis() or {})

@@ -30,11 +30,6 @@ from typing import Mapping
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
-# re-export: call sites use ``shd.shard_map`` and get version compat free
-shard_map = compat.shard_map
-
 _BATCH_AXIS_NAMES = ("pod", "data")
 _MODEL_AXIS_NAME = "model"
 

@@ -76,10 +76,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:  # JAX >= 0.6 removed the jaxpr types from the jax.core namespace
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:  # JAX 0.4.x
-    from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The one place Pallas interpret mode is decided.  An explicit flag
+    wins; ``None`` interprets exactly when JAX's default backend is not a
+    TPU (the CPU test runs), so nothing on a TPU runs the interpreter
+    unless a caller asks for it."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def count_pallas_calls(fn, *args, **kwargs) -> int:
@@ -124,7 +131,7 @@ def frontier_step_blocks(
     block_rows: jax.Array,  # (nnz,) int32
     block_cols: jax.Array,  # (nnz,) int32, non-decreasing
     block_size: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns the raw count matrix (m_pad, V_pad); caller thresholds >0."""
     m_pad, v_pad = frontier.shape
@@ -142,7 +149,7 @@ def frontier_step_blocks(
         _frontier_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, v_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_rows, block_cols, frontier, tiles)
 
 
@@ -218,7 +225,7 @@ def fused_level_blocks(
     o_cols: jax.Array,  # (n_steps,) int32: output col-block = tile block col
     block_size: int,
     q_pad: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
     n_out_rows: int | None = None,  # output height; default = frontier height
 ) -> jax.Array:
     """One BFS level over ALL transitions in a single pallas_call.
@@ -269,8 +276,25 @@ def fused_level_blocks(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols, frontier, tiles)
+
+
+def _or_of_and(f: jax.Array, a: jax.Array) -> jax.Array:
+    """Bitwise OR-of-AND of (q_pad, B) uint32 lane words ``f`` with a
+    (B, B) bool adjacency ``a``: word bit q of ``out[r, j]`` is
+    ``OR_v (f[r, v] bit q AND a[v, j])``.
+
+    The select is laid out (v, r, j) — contraction axis leading — so the
+    OR over v is a halving tree of elementwise ``|`` over whole (q_pad, B)
+    slabs.  The TPU Pallas lowering has no bitwise reduction primitive
+    (a generic ``lax.reduce`` with ``bitwise_or`` does not lower)."""
+    c = jnp.where(a[:, None, :], f.T[:, :, None], jnp.uint32(0))
+    while (n := c.shape[0]) > 1:
+        h = n // 2
+        folded = c[:h] | c[h : 2 * h]
+        c = folded if n % 2 == 0 else jnp.concatenate([folded, c[2 * h :]])
+    return c[0]
 
 
 def _packed_level_kernel(
@@ -285,9 +309,9 @@ def _packed_level_kernel(
     a word is query lane ``row·32 + q``'s frontier bit for that node.
     The tile stays the staged f32 tensor; ``a != 0`` recovers the
     boolean adjacency in-kernel, so one Stage-A staging serves both the
-    f32 matmul and the packed kernel.  The OR-of-AND is a broadcast
-    select to (q_pad, B, B) — lane words masked by the adjacency column
-    — reduced with bitwise OR over the contraction axis.  ``firsts`` /
+    f32 matmul and the packed kernel.  The OR-of-AND is
+    :func:`_or_of_and`: lane words masked by the adjacency, OR-folded
+    over the contraction axis.  ``firsts`` /
     ``valids`` keep the exact semantics of :func:`_fused_level_kernel`:
     zero-init on the output block's first step, early-out on cover and
     shape-class padding steps."""
@@ -299,13 +323,8 @@ def _packed_level_kernel(
 
     @pl.when(valids_ref[i] == 1)
     def _accumulate():
-        f = f_ref[...]  # (q_pad, B) uint32 lane words
         a = a_ref[0] != 0.0  # (B, B) bool — shared f32 staging
-        # contrib[r, v, j] = f[r, v] if a[v, j] else 0; OR over v
-        contrib = jnp.where(a[None, :, :], f[:, :, None], jnp.uint32(0))
-        o_ref[...] = o_ref[...] | jax.lax.reduce(
-            contrib, jnp.uint32(0), jax.lax.bitwise_or, (1,)
-        )
+        o_ref[...] = o_ref[...] | _or_of_and(f_ref[...], a)
 
 
 def _packed_level_kernel_u32(
@@ -315,7 +334,7 @@ def _packed_level_kernel_u32(
     """The fully bitpacked inner step — packed frontier × packed tiles:
     both operands are uint32 words, the adjacency bit-plane unpacks to a
     bool mask in-register (:func:`_unpack_tile_bits`) and the product is
-    the same select + OR-reduce as :func:`_packed_level_kernel` — no f32
+    the same :func:`_or_of_and` as :func:`_packed_level_kernel` — no f32
     threshold anywhere in the step, popcount-free boolean algebra on the
     VPU."""
     i = pl.program_id(0)
@@ -326,12 +345,8 @@ def _packed_level_kernel_u32(
 
     @pl.when(valids_ref[i] == 1)
     def _accumulate():
-        f = f_ref[...]  # (q_pad, B) uint32 lane words
         a = _unpack_tile_bits(a_ref[0], block_size)  # (B, B) bool
-        contrib = jnp.where(a[None, :, :], f[:, :, None], jnp.uint32(0))
-        o_ref[...] = o_ref[...] | jax.lax.reduce(
-            contrib, jnp.uint32(0), jax.lax.bitwise_or, (1,)
-        )
+        o_ref[...] = o_ref[...] | _or_of_and(f_ref[...], a)
 
 
 def packed_level_blocks(
@@ -346,7 +361,7 @@ def packed_level_blocks(
     o_cols: jax.Array,  # (n_steps,) int32
     block_size: int,
     q_pad: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
     n_out_rows: int | None = None,
 ) -> jax.Array:
     """One bitpacked BFS level over ALL transitions in a single
@@ -393,5 +408,5 @@ def packed_level_blocks(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols, frontier, tiles)

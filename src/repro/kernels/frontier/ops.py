@@ -60,8 +60,9 @@ Four execution paths share the staged tiles:
   ``multi_source_reach_baseline`` loops levels on the host.  Kept as the
   dispatch-count/perf baseline (see ``benchmarks/frontier_level.py``).
 
-On CPU pass ``interpret=True`` (the validation mode); on TPU the same
-code JITs to MXU tile products.
+Every wrapper takes ``interpret=None``, resolved by
+:func:`repro.kernels.frontier.frontier.resolve_interpret`: the Pallas
+interpreter on CPU (the validation mode), the compiled kernels on a TPU.
 """
 
 from __future__ import annotations
@@ -82,6 +83,7 @@ from repro.kernels.frontier.frontier import (
     frontier_step_blocks,
     fused_level_blocks,
     packed_level_blocks,
+    resolve_interpret,
 )
 from repro.kernels.frontier.ref import (
     TILE_DTYPES,
@@ -584,11 +586,17 @@ class ShardedTileBuckets:
 
 
 def bucket_staged_sites(
-    staged: StagedShardedGraph, axis_size: int = 1, floor: int = BUCKET_FLOOR
+    staged: StagedShardedGraph,
+    axis_size: int = 1,
+    floor: int = BUCKET_FLOOR,
+    sharding: jax.sharding.Sharding | None = None,
 ) -> ShardedTileBuckets:
     """Group the staged per-site slabs into power-of-two tile shape
     buckets and stack each bucket's slabs on device (Stage A, cached per
     shape bucket by :class:`repro.core.plans.GraphPlanStore`).
+    ``sharding`` places each stack, typically split over the mesh's site
+    axes so that every device holds only its own sites' rows (default:
+    the default device).
 
     Quantization exists to let several members share ONE jitted program
     (and, across devices, one SPMD shape) — a bucket that ends up with a
@@ -626,7 +634,10 @@ def bucket_staged_sites(
         for row, s in enumerate(sites):
             stack[row, : n_tiles[s]] = staged.site_tiles[s]
         buckets.append(
-            TileBucket(n_tiles=cls, slots=slots, sites=sites, tiles=jnp.asarray(stack))
+            TileBucket(
+                n_tiles=cls, slots=slots, sites=sites,
+                tiles=jax.device_put(stack, sharding),
+            )
         )
     return ShardedTileBuckets(
         axis_size=axis_size, s_local=s_local, floor=floor, buckets=tuple(buckets)
@@ -1100,13 +1111,14 @@ def _fused_expand(
 def expand_level_fused(
     plan: FusedLevelPlan,
     frontier: jnp.ndarray,  # (n_states * q_pad, v_pad) f32 0/1
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One BFS level over all grounded transitions — ONE pallas_call."""
     return _fused_expand(
         frontier, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
-        block_size=plan.block_size, q_pad=plan.q_pad, interpret=interpret,
+        block_size=plan.block_size, q_pad=plan.q_pad,
+        interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
     )
 
@@ -1153,14 +1165,14 @@ def reach_fixpoint(
     plan: FusedLevelPlan,
     frontier0: jnp.ndarray,  # (n_states * q_pad, v_pad) f32 0/1
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Visited product states (same layout as ``frontier0``) at fixpoint."""
     return _reach_fixpoint(
         frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
         block_size=plan.block_size, q_pad=plan.q_pad,
-        max_levels=max_levels, interpret=interpret,
+        max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
     )
 
@@ -1225,7 +1237,7 @@ def reach_fixpoint_levels(
     plan: FusedLevelPlan,
     frontier0: jnp.ndarray,  # (n_states * q_pad, v_pad) f32 0/1
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """:func:`reach_fixpoint` + BFS discovery levels (same layout, f32,
     ``INF_LEVEL`` = unreached) for host-side witness reconstruction.
@@ -1235,7 +1247,7 @@ def reach_fixpoint_levels(
         frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
         block_size=plan.block_size, q_pad=plan.q_pad,
-        max_levels=max_levels, interpret=interpret,
+        max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
     )
 
@@ -1278,7 +1290,7 @@ def count_paths_bounded(
     frontier0: jnp.ndarray,  # (n_states * q_pad, v_pad) f32 start counts
     accepting: tuple[int, ...],
     n_levels: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Bounded-length counting-semiring sum over the SAME Stage-B level
     schedule the boolean fixpoint runs: drop the saturating ``min(·, 1)``
@@ -1302,7 +1314,7 @@ def count_paths_bounded(
         frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
         block_size=plan.block_size, q_pad=plan.q_pad, n_levels=n_levels,
-        interpret=interpret, union_members=plan.union_members,
+        interpret=resolve_interpret(interpret), union_members=plan.union_members,
         n_states=plan.n_states, accepting=tuple(accepting),
     )
 
@@ -1326,7 +1338,7 @@ def multi_query_reach(
     bg: BlockedGraph,
     start_masks: np.ndarray,  # (Q, n_nodes) f32 0/1 — one row per query
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
     plan: FusedLevelPlan | None = None,
 ) -> np.ndarray:
     """Fixpoint reachability for Q stacked queries; returns (Q, n_nodes)
@@ -1360,7 +1372,7 @@ def multi_source_reach(
     bg: BlockedGraph,
     start_mask: np.ndarray,
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
     plan: FusedLevelPlan | None = None,
 ) -> np.ndarray:
     """Single-query fixpoint reachability on the fused level kernel."""
@@ -1436,7 +1448,7 @@ def _packed_expand(
 def expand_level_packed(
     plan: FusedLevelPlan,
     frontier: jnp.ndarray,  # (n_states * q_pad, v_pad) uint32 lane words
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One packed BFS level over all grounded transitions — ONE
     pallas_call on the SAME Stage-B plan the f32 path uses (the staged
@@ -1444,7 +1456,8 @@ def expand_level_packed(
     return _packed_expand(
         frontier, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
-        block_size=plan.block_size, q_pad=plan.q_pad, interpret=interpret,
+        block_size=plan.block_size, q_pad=plan.q_pad,
+        interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
     )
 
@@ -1489,14 +1502,14 @@ def reach_fixpoint_packed(
     plan: FusedLevelPlan,
     frontier0: jnp.ndarray,  # (n_states * q_pad, v_pad) uint32 lane words
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Visited lane words (same layout as ``frontier0``) at fixpoint."""
     return _reach_fixpoint_packed(
         frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
         block_size=plan.block_size, q_pad=plan.q_pad,
-        max_levels=max_levels, interpret=interpret,
+        max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
     )
 
@@ -1558,7 +1571,7 @@ def reach_fixpoint_packed_levels(
     plan: FusedLevelPlan,
     frontier0: jnp.ndarray,  # (n_states * q_pad, v_pad) uint32 lane words
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """:func:`reach_fixpoint_packed` + per-lane discovery levels:
     returns (visited lane words, levels) where levels is (n_states,
@@ -1570,7 +1583,7 @@ def reach_fixpoint_packed_levels(
         frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
         plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
         block_size=plan.block_size, q_pad=plan.q_pad,
-        max_levels=max_levels, interpret=interpret,
+        max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
     )
 
@@ -1580,7 +1593,7 @@ def multi_query_reach_packed(
     bg: BlockedGraph,
     start_masks: np.ndarray,  # (Q, n_nodes) 0/1 — one row per query lane
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
     plan: FusedLevelPlan | None = None,
 ) -> np.ndarray:
     """Fixpoint reachability for Q bitpacked queries; returns (Q,
@@ -1641,7 +1654,7 @@ def expand_level(
     ca: CompiledAutomaton,
     bg: BlockedGraph,
     frontier: jnp.ndarray,  # (n_states, v_pad) f32 0/1 — rows = automaton states
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One BFS level over all grounded transitions; returns new 0/1 mask.
 
@@ -1661,7 +1674,7 @@ def expand_level(
             tiles, rows, cols = entry
             counts = _expand_one(
                 frontier[t.src], tiles, rows, cols,
-                block_size=bg.block_size, interpret=interpret,
+                block_size=bg.block_size, interpret=resolve_interpret(interpret),
             )
             out = out.at[t.dst].max(counts)
     return (out > 0).astype(jnp.float32)
@@ -1672,7 +1685,7 @@ def multi_source_reach_baseline(
     bg: BlockedGraph,
     start_mask: np.ndarray,
     max_levels: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> np.ndarray:
     """Fixpoint reachability with per-transition level dispatches and a
     host loop (one device→host sync per level) — the pre-fusion path,

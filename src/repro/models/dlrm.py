@@ -170,7 +170,7 @@ def embedding_bag_sharded(table, idx, rules: shd.Rules):
     if pad:
         table = jnp.pad(table, ((0, pad), (0, 0)))
     idx_spec = rules.fit(P(rules.batch, None), idx.shape)
-    return shd.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(rules.model_axis, None), idx_spec),

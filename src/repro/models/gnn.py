@@ -69,7 +69,7 @@ def edge_shard_map(fn, rules: shd.Rules, n_edge_arrays: int, n_rep_arrays: int):
     axes = tuple(rules.batch_axes) + ((rules.model_axis,) if rules.model_axis else ())
     espec = P(axes)
     in_specs = tuple([espec] * n_edge_arrays + [P()] * n_rep_arrays)
-    return shd.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
 
@@ -766,7 +766,7 @@ def equiformer_energy_big(cfg: EquiformerConfig, rules: shd.Rules, params, batch
 
     nspec = P(rules.model_axis)
     espec = P(data_axes if data_axes else None)
-    fn = shd.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(nspec, P(rules.model_axis, None), nspec, espec, espec, espec)

@@ -416,7 +416,7 @@ def apply_moe(
         spec_out = P(rules.model_axis, rules.batch_axes, None)
     else:
         spec_in = spec_out = P(rules.model_axis, None, None)
-    return shd.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_x, P(None, None), spec_in, spec_in, spec_out),

@@ -395,6 +395,7 @@ class ExecutorCache:
             bucket_id = self.plan_store.tile_buckets(
                 placement, block_size, axis_size, epoch=stats_epoch, floor=floor,
                 tile_dtype=eff_dtype,
+                sharding=strategies.site_sharding(mesh, site_axes),
             ).bucket_id
         gkey = self.graph_key(
             stats_epoch, backend, block_size, graph, placement, bucket_id
@@ -439,6 +440,13 @@ class ExecutorCache:
 
     def __len__(self) -> int:
         return len(self._lru)
+
+    def interpret_flags(self) -> list[bool]:
+        """The resolved Pallas interpret mode of every cached fused
+        executor (the ``reference`` backend runs no Pallas kernel)."""
+        return [
+            e.fn.interpret for e in self._lru.values() if hasattr(e.fn, "interpret")
+        ]
 
     @property
     def hit_rate(self) -> float:

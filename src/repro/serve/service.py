@@ -235,9 +235,11 @@ class QueryService:
         # the swap inside the lock are each atomic under the GIL
         self._flush_lock = threading.Lock()
         self._flush_owner: int | None = None
-        # stage the padded site arrays once per epoch; static per placement
+        # stage the padded site arrays once per epoch, each site's rows
+        # on the device of its mesh position; static per placement
         self._device_arrays = self.plan_store.site_device_arrays(
-            placement, epoch=self.stats_epoch
+            placement, epoch=self.stats_epoch,
+            sharding=strategies.site_sharding(mesh, self.config.site_axes),
         )
 
     # -- stats epoch --------------------------------------------------------
@@ -256,7 +258,8 @@ class QueryService:
         self.stats_epoch += 1
         self.exec_cache.drop_epoch(self.stats_epoch)  # also sweeps the plan store
         self._device_arrays = self.plan_store.site_device_arrays(
-            self.placement, epoch=self.stats_epoch
+            self.placement, epoch=self.stats_epoch,
+            sharding=strategies.site_sharding(self.mesh, self.config.site_axes),
         )
 
     # -- admission ----------------------------------------------------------

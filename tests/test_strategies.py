@@ -123,3 +123,68 @@ def test_overlay_probes():
     placement = distribute(g, 150, replication_rate=0.2, seed=0)
     k_hat = net.probe_replication(placement, n_samples=128)
     assert abs(k_hat - placement.replication_rate) < 0.08
+
+
+def test_resolve_interpret_is_the_one_decision():
+    """None interprets exactly off-TPU; an explicit flag always wins."""
+    import jax
+
+    from repro.kernels.frontier.frontier import resolve_interpret
+
+    assert resolve_interpret() is (jax.default_backend() != "tpu")
+    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+
+
+FUSED_BACKENDS = ["frontier_kernel", "frontier_kernel_packed", "frontier_kernel_sharded"]
+
+
+@pytest.mark.parametrize("backend", FUSED_BACKENDS)
+def test_fused_executor_records_resolved_interpret(backend):
+    """Every fused executor carries the interpret mode it resolved — the
+    default off-TPU, or the caller's explicit choice."""
+    import jax
+
+    g = random_labeled_graph(40, 170, 4, seed=3)
+    placement = distribute(g, n_sites=2, replication_rate=0.5, seed=0)
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    ca = paa.compile_query("(l0|l1)* l2", g)
+    kw = dict(backend=backend, graph=g, placement=placement, block_size=8)
+    default = strategies.make_s2_step_fn(ca, g.n_nodes, mesh, **kw)
+    assert default.interpret is (jax.default_backend() != "tpu")
+    compiled = strategies.make_s2_step_fn(ca, g.n_nodes, mesh, interpret=False, **kw)
+    assert compiled.interpret is False
+
+
+@pytest.mark.parametrize("backend", FUSED_BACKENDS)
+def test_fused_executor_takes_staged_arrays_as_arguments(backend):
+    """The staged tile store reaches the jitted program as an argument:
+    no array as large as the tile tensor is a constant of the program
+    (a closed-over store would be compiled in, one copy per executor)."""
+    import jax
+
+    g = random_labeled_graph(40, 170, 4, seed=3)
+    placement = distribute(g, n_sites=2, replication_rate=0.5, seed=0)
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    ca = paa.compile_query("(l0|l1)* l2", g)
+    fn = strategies.make_s2_step_fn(
+        ca, g.n_nodes, mesh, backend=backend, graph=g, placement=placement, block_size=8
+    )
+    site = np.zeros((1, 1), np.int32)
+    starts = np.arange(0, g.n_nodes, 5, dtype=np.int32)
+    closed = jax.make_jaxpr(fn)(site, site, site, site.astype(bool), starts)
+    (call,) = [e for e in closed.jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    # the tile store (n_tiles, B, B | W), or the sharded stack of them,
+    # is an operand of the call ...
+    store = max(
+        (v.aval for v in call.invars if v.aval.ndim >= 3 and v.aval.shape[-2] == 8),
+        key=lambda a: a.size,
+    )
+    # ... and nothing of its size was compiled in as a constant
+    consts = call.params["jaxpr"].consts
+    assert all(np.size(c) < store.size for c in consts), [np.shape(c) for c in consts]
+    acc, *_ = fn(site, site, site, site.astype(bool), starts)
+    dg = to_device_graph(g)
+    for i, s in enumerate(starts):
+        assert (np.asarray(acc[i]) == np.asarray(paa.answers_single_source(ca, dg, int(s)))).all()
