@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import spans
 from repro.core import paa
 from repro.core.automaton import FWD, CompiledAutomaton
 from repro.core.regex import Node, has_wildcard, labels_of, query_size
@@ -294,17 +295,28 @@ def s1_collect(
     site_arrays = device_arrays if device_arrays is not None else placement.padded_device_arrays()
     if cap is None:
         cap = site_arrays["src"].shape[1]
-    while True:
-        src, lbl, dst, valid, overflow = s1_gather(mesh, site_arrays, label_mask, cap, site_axes)
-        if overflow == 0:
-            break
-        cap = min(2 * cap, site_arrays["src"].shape[1])  # planner underestimated: grow
+    with spans.span("s1.collect") as sp:
+        while True:
+            with spans.span("s1.gather") as sg:
+                src, lbl, dst, valid, overflow = s1_gather(
+                    mesh, site_arrays, label_mask, cap, site_axes
+                )
+                sg.count("cap", cap)
+                sg.count("bytes", src.nbytes + lbl.nbytes + dst.nbytes + valid.nbytes)
+            if overflow == 0:
+                break
+            sp.count("retries")
+            cap = min(2 * cap, site_arrays["src"].shape[1])  # planner underestimated: grow
 
-    v = valid.reshape(-1)
-    sub = LabeledGraph(
-        graph.n_nodes, src.reshape(-1)[v], lbl.reshape(-1)[v], dst.reshape(-1)[v], graph.labels
-    )
-    return sub.dedup()  # replicated copies collapse at the querying site
+        with spans.span("s1.dedup") as sd:
+            v = valid.reshape(-1)
+            sub = LabeledGraph(
+                graph.n_nodes, src.reshape(-1)[v], lbl.reshape(-1)[v], dst.reshape(-1)[v],
+                graph.labels,
+            )
+            sub = sub.dedup()  # replicated copies collapse at the querying site
+            sd.count("edges", sub.n_edges)
+        return sub
 
 
 def s1_execute(
@@ -981,18 +993,19 @@ def _make_frontier_packed_step_fn(
             visited, frontier, lev, done, q_bc, d_s2, n_bc = state[:7]
             fr3 = frontier.reshape(n_states, q_pad, v_pad)
             new_done = []
-            for gi, rows in enumerate(state_rows):
-                now_g = jax.lax.reduce(
-                    fr3[rows], jnp.uint32(0), jax.lax.bitwise_or, (0,)
-                )  # (q_pad, v_pad) lane words
-                new_g = now_g & ~done[gi]
-                bits = lane_bits(new_g)  # per-lane 0/1, meter dots only
-                cnt = bits.sum(axis=1)
-                q_bc = q_bc + pay_c[gi] * cnt
-                n_bc = n_bc + cnt
-                d_s2 = d_s2 + EDGE_SYMBOLS * (bits * deg_c[gi][None, :]).sum(axis=1)
-                new_done.append(done[gi] | now_g)
-            done = jnp.stack(new_done) if new_done else done
+            with jax.named_scope("rpq/meters"):
+                for gi, rows in enumerate(state_rows):
+                    now_g = jax.lax.reduce(
+                        fr3[rows], jnp.uint32(0), jax.lax.bitwise_or, (0,)
+                    )  # (q_pad, v_pad) lane words
+                    new_g = now_g & ~done[gi]
+                    bits = lane_bits(new_g)  # per-lane 0/1, meter dots only
+                    cnt = bits.sum(axis=1)
+                    q_bc = q_bc + pay_c[gi] * cnt
+                    n_bc = n_bc + cnt
+                    d_s2 = d_s2 + EDGE_SYMBOLS * (bits * deg_c[gi][None, :]).sum(axis=1)
+                    new_done.append(done[gi] | now_g)
+                done = jnp.stack(new_done) if new_done else done
             fre = fops.extend_frontier_packed(
                 frontier, plan.union_members, n_states, q_pad
             )
@@ -1019,18 +1032,20 @@ def _make_frontier_packed_step_fn(
             state0 = state0 + (
                 jnp.where(state_lane_bits(flat0), 1.0, INF_LEVEL),
             )
-        final = jax.lax.while_loop(cond, body, state0)
-        visited, q_bc, d_s2, n_bc = final[0], final[4], final[5], final[6]
-        vis3 = visited.reshape(n_states, q_pad, v_pad)
-        acc = jnp.zeros((q_pad, v_pad), jnp.uint32)
-        for qf in ca.accepting:
-            acc = acc | vis3[qf]
-        answers = lane_bits(acc)[:, :n_nodes] > 0
+        with jax.named_scope("rpq/fixpoint"):
+            final = jax.lax.while_loop(cond, body, state0)
+        visited, lev, q_bc, d_s2, n_bc = final[0], final[2], final[4], final[5], final[6]
+        with jax.named_scope("rpq/answers"):
+            vis3 = visited.reshape(n_states, q_pad, v_pad)
+            acc = jnp.zeros((q_pad, v_pad), jnp.uint32)
+            for qf in ca.accepting:
+                acc = acc | vis3[qf]
+            answers = lane_bits(acc)[:, :n_nodes] > 0
         out = (answers, q_bc, d_s2 * replication_factor, n_bc)
         if witness:
             # (n_states, q_pack, v_pad) -> (q_pack, n_states, n_nodes)
             out = out + (final[7].transpose(1, 0, 2)[:, :, :n_nodes],)
-        return out
+        return out + (lev,)
 
     lane_ids = jnp.arange(q_pack, dtype=jnp.int32)
 
@@ -1064,10 +1079,12 @@ def _make_frontier_packed_step_fn(
             res = res + (
                 out[4].reshape(n_chunks * q_pack, n_states, n_nodes)[:b],
             )
-        return res
+        # the level kernel's calls, all chunks: one scalar, read back
+        # only while spans record (s2_execute)
+        return res + (out[-1].sum(),)
 
     # retrieval is modeled on the staged global tiles
-    return _bind_operands(run, operands, interpret)
+    return _bind_operands(run, operands, interpret, kernel_bytes=plan.kernel_bytes)
 
 
 def _schedule_operands(plan) -> tuple:
@@ -1080,7 +1097,7 @@ def _schedule_operands(plan) -> tuple:
     )
 
 
-def _bind_operands(run, operands, interpret: bool):
+def _bind_operands(run, operands, interpret: bool, kernel_bytes: int | None = None):
     """Wrap ``run(operands, starts)`` in the shared step contract
     ``fn(src, lbl, dst, mask, starts)`` of the fused executors; they
     ignore the site edge arrays and read only their staged operands.
@@ -1090,7 +1107,10 @@ def _bind_operands(run, operands, interpret: bool):
     the program closed over would be embedded in it as a constant, so
     every executor would compile, cache and hold its own copy of the
     tile store.  ``fn.interpret`` records the resolved Pallas mode, and
-    ``fn.clear_cache`` drops the compiled programs on eviction."""
+    ``fn.clear_cache`` drops the compiled programs on eviction.  Where
+    ``kernel_bytes`` (the level kernel's HBM bytes per call) is given,
+    ``run``'s last output is the number of level-kernel calls it made,
+    and ``fn.kernel_bytes`` says so to :func:`s2_execute`."""
     jitted = jax.jit(run)
 
     def fn(src, lbl, dst, mask, starts):
@@ -1099,6 +1119,7 @@ def _bind_operands(run, operands, interpret: bool):
 
     fn.clear_cache = jitted.clear_cache
     fn.interpret = interpret
+    fn.kernel_bytes = kernel_bytes
     return fn
 
 
@@ -1579,22 +1600,31 @@ def s2_execute(
             tile_dtype=tile_dtype,
             tile_store_budget_bytes=tile_store_budget_bytes,
         )
-    out = step_fn(
-        jnp.asarray(arrays["src"]),
-        jnp.asarray(arrays["lbl"]),
-        jnp.asarray(arrays["dst"]),
-        jnp.asarray(arrays["mask"]),
-        jnp.asarray(np.asarray(start_nodes, np.int32)),
-    )
-    acc, q_bc, d_s2, n_bc = out[:4]
-    extras = out[4:]
-    levels = None
-    if semantics == "witness":
-        # the levels plane is always the LAST extra output
-        levels = np.asarray(extras[-1])  # (B, n_states, n_nodes)
-        extras = extras[:-1]
-    d_sites = np.asarray(extras[0]) if extras else None  # (n_sites, B)
-    q_bc, d_s2, n_bc = (np.asarray(a) for a in (q_bc, d_s2, n_bc))
+    with spans.span("s2.dispatch"):
+        out = step_fn(
+            jnp.asarray(arrays["src"]),
+            jnp.asarray(arrays["lbl"]),
+            jnp.asarray(arrays["dst"]),
+            jnp.asarray(arrays["mask"]),
+            jnp.asarray(np.asarray(start_nodes, np.int32)),
+        )
+    kernel_bytes = getattr(step_fn, "kernel_bytes", None)
+    if kernel_bytes is not None:  # the level-kernel call count comes last
+        out, n_calls = out[:-1], out[-1]
+    with spans.span("s2.fetch") as sp:
+        acc, q_bc, d_s2, n_bc = (np.asarray(a) for a in out[:4])
+        extras = out[4:]
+        levels = None
+        if semantics == "witness":
+            # the levels plane is always the LAST extra output
+            levels = np.asarray(extras[-1])  # (B, n_states, n_nodes)
+            extras = extras[:-1]
+        d_sites = np.asarray(extras[0]) if extras else None  # (n_sites, B)
+        if sp:
+            sp.count("answer_bytes", acc.nbytes + (levels.nbytes if levels is not None else 0))
+            if kernel_bytes is not None:
+                sp.count("levels", int(n_calls))
+                sp.count("kernel_bytes", kernel_bytes)
     k_rep = max(placement.replication_factor, 1e-9)
     costs = [
         StrategyCost(
@@ -1610,5 +1640,5 @@ def s2_execute(
         for i in range(len(q_bc))
     ]
     if semantics == "witness":
-        return np.asarray(acc), costs, levels
-    return np.asarray(acc), costs
+        return acc, costs, levels
+    return acc, costs

@@ -148,6 +148,7 @@ def frontier_step_blocks(
     return pl.pallas_call(
         _frontier_kernel,
         grid_spec=grid_spec,
+        name="rpq_frontier_step",
         out_shape=jax.ShapeDtypeStruct((m_pad, v_pad), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(block_rows, block_cols, frontier, tiles)
@@ -275,6 +276,7 @@ def fused_level_blocks(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="rpq_fused_level",
         out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols, frontier, tiles)
@@ -407,6 +409,7 @@ def packed_level_blocks(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="rpq_packed_level",
         out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), jnp.uint32),
         interpret=resolve_interpret(interpret),
     )(firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols, frontier, tiles)

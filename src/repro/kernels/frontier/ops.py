@@ -793,6 +793,51 @@ class FusedLevelPlan:
     # dispatch off the array dtype; the field gates the f32-only
     # semirings (witness levels, counting) at the wrapper layer
     tile_dtype: str = "f32"
+    # HBM bytes one call of the level kernel moves (level_kernel_bytes)
+    kernel_bytes: int = 0
+
+
+def level_kernel_bytes(
+    tile_ids: np.ndarray,
+    f_rows: np.ndarray,
+    f_cols: np.ndarray,
+    o_rows: np.ndarray,
+    o_cols: np.ndarray,
+    q_pad: int,
+    block_size: int,
+    tile_block_bytes: int,
+) -> int:
+    """HBM bytes one call of the fused or packed level kernel moves for a
+    Stage-B schedule (one BFS level).
+
+    The kernel's pipeline copies a block in only where the block's index
+    differs from the step before (and writes an output block back when
+    the grid leaves it), so this counts, over the grid steps: the tile
+    block (``tile_block_bytes``) where ``tile_ids`` changes, the
+    ``(q_pad, block_size)`` frontier block where ``(f_rows, f_cols)``
+    changes, the output block of the same size where ``(o_rows, o_cols)``
+    changes, and the seven int32 scalar-prefetch arrays.  Frontier and
+    output elements are 4 bytes (f32 rows or uint32 lane words).  A
+    cover step's zero tile counts like any other: the copy does not know
+    that ``valids`` skips the product."""
+    n = len(tile_ids)
+    if n == 0:
+        return 0
+
+    def fetches(*cols) -> int:
+        same = np.ones(n - 1, bool)
+        for c in cols:
+            c = np.asarray(c)
+            same &= c[1:] == c[:-1]
+        return 1 + int(np.count_nonzero(~same))
+
+    row_block = q_pad * block_size * 4
+    return (
+        fetches(tile_ids) * tile_block_bytes
+        + fetches(f_rows, f_cols) * row_block
+        + fetches(o_rows, o_cols) * row_block
+        + 7 * 4 * n
+    )
 
 
 def required_offset_keys(ca: CompiledAutomaton) -> tuple[tuple[int, int], ...]:
@@ -865,6 +910,8 @@ def build_level_schedule(
     nb = staged.v_pad // staged.block_size
     frow_map, union_members = fanin_frontier_rows(ca)
     arr, firsts, valids, n_real = _schedule_steps(ca, staged.offsets, nb, frow_map)
+    tiles = staged.tiles
+    tile_block_bytes = staged.block_size * int(tiles.shape[2]) * tiles.dtype.itemsize
     return FusedLevelPlan(
         n_states=ca.n_states,
         n_nodes=staged.n_nodes,
@@ -882,6 +929,10 @@ def build_level_schedule(
         o_rows=jnp.asarray(arr[:, 0]),
         o_cols=jnp.asarray(arr[:, 1]),
         tile_dtype=staged.tile_dtype,
+        kernel_bytes=level_kernel_bytes(
+            arr[:, 4], arr[:, 2], arr[:, 3], arr[:, 0], arr[:, 1],
+            q_pad, staged.block_size, tile_block_bytes,
+        ),
     )
 
 

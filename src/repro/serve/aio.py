@@ -43,11 +43,12 @@ are bit-identical to the sync path — the async layer only decides
 *when* the same flush pipeline runs.
 
 Metrics land in the stable ``aio`` block of the service summary
-(:mod:`repro.serve.metrics`): per-class queue depth, admission
-accept/reject counters, window fill accounting, and fixed-bucket
-latency histograms that p50/p99/p999 derive from without keeping
-samples.  ``benchmarks/serve_async.py`` drives all of this with an
-open-loop Poisson load generator.
+(:mod:`repro.serve.metrics`) when :meth:`AsyncQueryService.summary` or
+``stop()`` reads them: per-class queue depth, admission accept/reject
+counters, window fill accounting, and fixed-bucket latency histograms
+that p50/p99/p999 derive from without keeping samples.
+``benchmarks/serve_async.py`` drives all of this with an open-loop
+Poisson load generator.
 """
 
 from __future__ import annotations
@@ -60,6 +61,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import spans
 from repro.serve import batcher
 from repro.serve import metrics as metrics_mod
 from repro.serve.metrics import SLO_CLASSES, LatencyHistogram
@@ -153,6 +155,7 @@ class _Pending:
     t_admit: float
     lane_key: tuple
     in_batch: bool = False
+    t_routed: float | None = None  # perf_counter at _route, while spans record
 
 
 @dataclasses.dataclass
@@ -306,28 +309,31 @@ class AsyncQueryService:
             raise RuntimeError("AsyncQueryService is not running — call start()")
         if slo not in SLO_CLASSES:
             raise ValueError(f"slo must be one of {SLO_CLASSES}, got {slo!r}")
-        now = self._clock()
-        ok, retry = self._bucket(tenant).try_take()
-        if not ok:
-            self._admission[slo]["rejected_rate_limited"] += 1
-            raise AdmissionRejected("rate_limited", retry)
-        if self._depth[slo] >= self.config.queue_depth[slo]:
-            self._admission[slo]["rejected_queue_full"] += 1
-            raise AdmissionRejected("queue_full", self._retry_after(now))
-        # plan at admission: hot classes are a plan-cache hit; the
-        # signature + cost forecast route and size the lane
-        ticket = self.service.plan_request(query, start_nodes, strategy, semantics)
-        pending = _Pending(
-            ticket=ticket,
-            tenant=tenant,
-            slo=slo,
-            future=asyncio.get_running_loop().create_future(),
-            t_admit=now,
-            lane_key=self._lane_key(ticket, slo),
-        )
-        self._admission[slo]["accepted"] += 1
-        self._depth[slo] += 1
-        self._route(pending, now)
+        with spans.span("aio.admit") as sp:  # no await inside: see repro.spans
+            now = self._clock()
+            ok, retry = self._bucket(tenant).try_take()
+            if not ok:
+                self._admission[slo]["rejected_rate_limited"] += 1
+                raise AdmissionRejected("rate_limited", retry)
+            if self._depth[slo] >= self.config.queue_depth[slo]:
+                self._admission[slo]["rejected_queue_full"] += 1
+                raise AdmissionRejected("queue_full", self._retry_after(now))
+            # plan at admission: hot classes are a plan-cache hit; the
+            # signature + cost forecast route and size the lane
+            ticket = self.service.plan_request(query, start_nodes, strategy, semantics)
+            if sp:
+                sp.request = ticket.id
+            pending = _Pending(
+                ticket=ticket,
+                tenant=tenant,
+                slo=slo,
+                future=asyncio.get_running_loop().create_future(),
+                t_admit=now,
+                lane_key=self._lane_key(ticket, slo),
+            )
+            self._admission[slo]["accepted"] += 1
+            self._depth[slo] += 1
+            self._route(pending, now)
         timeout_s = timeout_s if timeout_s is not None else self.config.default_timeout_s
         try:
             if timeout_s is not None:
@@ -343,6 +349,8 @@ class AsyncQueryService:
         return (slo, "S1")  # S1 requests coalesce by union mask at flush
 
     def _route(self, pending: _Pending, now: float) -> None:
+        if spans.recording():
+            pending.t_routed = time.perf_counter()
         lane = self._lanes.get(pending.lane_key)
         if lane is None:
             window = self._window_s(pending)
@@ -451,6 +459,15 @@ class AsyncQueryService:
                 batch.append(p)
         if not batch:
             return
+        if spans.recording():
+            t_handed = time.perf_counter()
+            for lane in lanes:
+                for p in lane.reqs:
+                    if p.in_batch and p.t_routed is not None:
+                        spans.interval(
+                            "aio.lane_wait", p.t_routed, t_handed, request=p.ticket.id,
+                            slo=p.slo, fill_flush=int(lane.fill_ready),
+                        )
         self._flushes += 1
         t0 = self._clock()
         try:
@@ -461,26 +478,27 @@ class AsyncQueryService:
         exec_s = self._clock() - t0
         self._observe_exec(lanes, forecast, exec_s)
         now = self._clock()
-        for p in batch:
-            self._depth[p.slo] -= 1
-            if p.future.done():  # cancelled while the batch executed:
-                # the work completed but the answer is discarded
-                self._admission[p.slo]["cancelled_mid_batch"] += 1
-                continue
-            t = p.ticket
-            if flush_err is not None and not t.done:
-                p.future.set_exception(flush_err)
-                self._admission[p.slo]["failed"] += 1
-            elif t.error is not None or not t.done:
-                p.future.set_exception(
-                    t.error if t.error is not None else RuntimeError("ticket unresolved")
-                )
-                self._admission[p.slo]["failed"] += 1
-            else:
-                p.future.set_result(t.result())
-                self._admission[p.slo]["completed"] += 1
-                self._hists[p.slo].observe(now - p.t_admit)
-        self._push_metrics()
+        with spans.span("aio.resolve") as sp:
+            sp.count("n", len(batch))
+            for p in batch:
+                self._depth[p.slo] -= 1
+                if p.future.done():  # cancelled while the batch executed:
+                    # the work completed but the answer is discarded
+                    self._admission[p.slo]["cancelled_mid_batch"] += 1
+                    continue
+                t = p.ticket
+                if flush_err is not None and not t.done:
+                    p.future.set_exception(flush_err)
+                    self._admission[p.slo]["failed"] += 1
+                elif t.error is not None or not t.done:
+                    p.future.set_exception(
+                        t.error if t.error is not None else RuntimeError("ticket unresolved")
+                    )
+                    self._admission[p.slo]["failed"] += 1
+                else:
+                    p.future.set_result(t.result())
+                    self._admission[p.slo]["completed"] += 1
+                    self._hists[p.slo].observe(now - p.t_admit)
 
     def _observe_exec(self, lanes: list[_Lane], forecast: float, exec_s: float) -> None:
         """Fold one measured flush back into the window-sizing EWMAs:

@@ -28,6 +28,8 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from repro import spans
+
 
 def bucket_size(n: int, multiple: int = 1, max_batch: int = 1024) -> int:
     """Smallest ``multiple × 2^k`` ≥ n, capped at the largest multiple of
@@ -129,7 +131,10 @@ def run_s2_group(
         chunk = starts[lo : lo + chunk_cap]
         size = bucket_size(len(chunk), multiple, max_batch)
         padded = pad_starts(chunk, size)
-        res = execute(padded, group[0])
+        with spans.span("s2.call") as sp:
+            res = execute(padded, group[0])
+            sp.count("starts", len(chunk))
+            sp.count("padded", size)
         acc, costs = res[0], res[1]
         acc_chunks.append(np.asarray(acc)[: len(chunk)])
         cost_chunks.append(costs[: len(chunk)])
@@ -137,24 +142,27 @@ def run_s2_group(
             lev_chunks.append(np.asarray(res[2])[: len(chunk)])
         pad_sizes.append(size)
 
-    acc_all = np.concatenate(acc_chunks) if acc_chunks else np.zeros((0, 0), bool)
-    costs_all = [c for chunk in cost_chunks for c in chunk]
-    lev_all = np.concatenate(lev_chunks) if lev_chunks else None
-    batch_of = np.zeros(len(starts), np.int32)
-    pos = 0
-    for size, chunk in zip(pad_sizes, acc_chunks):
-        batch_of[pos : pos + len(chunk)] = size
-        pos += len(chunk)
+    # the group's rows and costs, gathered from its calls and sliced per item
+    with spans.span("s2.rows") as sp:
+        acc_all = np.concatenate(acc_chunks) if acc_chunks else np.zeros((0, 0), bool)
+        costs_all = [c for chunk in cost_chunks for c in chunk]
+        lev_all = np.concatenate(lev_chunks) if lev_chunks else None
+        batch_of = np.zeros(len(starts), np.int32)
+        pos = 0
+        for size, chunk in zip(pad_sizes, acc_chunks):
+            batch_of[pos : pos + len(chunk)] = size
+            pos += len(chunk)
 
-    out: dict[int, tuple[np.ndarray, list, int, np.ndarray | None]] = {}
-    for sl in slices:
-        batch = int(batch_of[sl.lo]) if sl.hi > sl.lo else 0
-        out[id(sl.item)] = (
-            acc_all[sl.lo : sl.hi],
-            costs_all[sl.lo : sl.hi],
-            batch,
-            lev_all[sl.lo : sl.hi] if lev_all is not None else None,
-        )
+        out: dict[int, tuple[np.ndarray, list, int, np.ndarray | None]] = {}
+        for sl in slices:
+            batch = int(batch_of[sl.lo]) if sl.hi > sl.lo else 0
+            out[id(sl.item)] = (
+                acc_all[sl.lo : sl.hi],
+                costs_all[sl.lo : sl.hi],
+                batch,
+                lev_all[sl.lo : sl.hi] if lev_all is not None else None,
+            )
+        sp.count("bytes", acc_all.nbytes)
     return out
 
 

@@ -8,16 +8,17 @@ counters: executor-cache and plan-store hit/miss rates, and the sharded
 plans' grid-step padding accounting ``plan_pad_waste``, and the frontier
 memory-roofline block ``frontier_mem`` (per-dtype executor counts,
 frontier bytes and lane capacity per fixpoint chunk, chunked Stage-A
-slice count), pushed by the service via
-:meth:`ServiceMetrics.set_cache_stats` each flush; all four are zeroed
-placeholders with the full key sets before the first flush).
+slice count), read from the caches by ``QueryService.summary()`` through
+:meth:`ServiceMetrics.set_cache_stats`; all four are zeroed placeholders
+with the full key sets until then).
 
 The async runtime adds one more stable block, ``aio`` (queue depth and
 admission accept/reject counters per SLO class, batch-window fill
 accounting, and a fixed-bucket :class:`LatencyHistogram` per class so
-p50/p99/p999 derive from counts without post-processing), pushed via
-:meth:`ServiceMetrics.set_aio_stats` and zero-initialized with the full
-key set for sync-only services.
+p50/p99/p999 derive from counts without post-processing), installed via
+:meth:`ServiceMetrics.set_aio_stats` by ``AsyncQueryService.summary()``
+and ``stop()``, and zero-initialized with the full key set for sync-only
+services.
 """
 
 from __future__ import annotations
@@ -119,7 +120,7 @@ def _empty_admission_stats() -> dict:
 
 def _empty_aio_stats() -> dict:
     # the async runtime's STABLE summary block (zero-initialized before
-    # the first event, pushed live by AsyncQueryService): queue depth
+    # the first event, installed by AsyncQueryService): queue depth
     # per SLO class, admission accept/reject counters per class, the
     # batching-window accounting, and the fixed-bucket latency
     # histograms p50/p99/p999 derive from
@@ -191,8 +192,8 @@ class ServiceMetrics:
         # executor-cache / plan-store counters: part of the STABLE summary
         # schema — the zeroed placeholders carry the full key sets of
         # ExecutorCache.stats() / GraphPlanStore.stats(), so consumers see
-        # one schema whether or not the service has pushed real numbers
-        # via set_cache_stats yet
+        # one schema whether or not the service has installed real
+        # numbers via set_cache_stats yet
         self._cache_stats: dict[str, dict] = {
             "exec_cache": _empty_exec_cache_stats(),
             "plan_store": _empty_plan_store_stats(),
@@ -200,13 +201,13 @@ class ServiceMetrics:
             "frontier_mem": _empty_frontier_mem_stats(),
         }
         # async-runtime block: zeroed full-schema placeholder until an
-        # AsyncQueryService pushes live numbers via set_aio_stats
+        # AsyncQueryService installs live numbers via set_aio_stats
         self._aio_stats: dict = _empty_aio_stats()
 
     def set_aio_stats(self, aio: dict) -> None:
         """Install the async runtime's admission/window/histogram block
-        (pushed by ``AsyncQueryService`` after every flush cycle, same
-        stable schema as the zeroed placeholder)."""
+        (installed by ``AsyncQueryService.summary()`` and ``stop()``,
+        same stable schema as the zeroed placeholder)."""
         self._aio_stats = dict(aio)
 
     def set_cache_stats(
@@ -218,9 +219,9 @@ class ServiceMetrics:
     ) -> None:
         """Install the current executor-cache / plan-store hit/miss
         counters, the sharded plans' grid-step padding accounting, and
-        the frontier memory-roofline block (the service pushes these
-        every flush, so summaries and the throughput benchmark see live
-        two-stage-compilation rates)."""
+        the frontier memory-roofline block (``QueryService.summary()``
+        reads them from the caches, so every summary sees the live
+        two-stage-compilation rates; no flush pays for them)."""
         if exec_cache is not None:
             self._cache_stats["exec_cache"] = dict(exec_cache)
         if plan_store is not None:

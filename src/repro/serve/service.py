@@ -26,6 +26,7 @@ callers enqueue a window of requests and flush once.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 
@@ -33,6 +34,7 @@ import numpy as np
 
 from jax.sharding import Mesh
 
+from repro import spans
 from repro.core import paa, planner, plans, strategies, witness
 from repro.core import regex as rx
 from repro.core.cost_model import NetworkParams
@@ -131,9 +133,13 @@ class Ticket:
     ``strategy``, and ``forecast_symbols`` carry the automaton
     signature, the effective strategy, and the §4 cost-model traffic
     forecast — the per-request signal the async layer's batching
-    windows and admission control size themselves from."""
+    windows and admission control size themselves from.  ``id`` is unique
+    in the process; the request's spans (:mod:`repro.spans`) carry it."""
+
+    _ids = itertools.count(1)
 
     def __init__(self, query: str, starts: np.ndarray):
+        self.id = next(Ticket._ids)
         self.query = query
         self.starts = starts
         self.done = False
@@ -235,6 +241,7 @@ class QueryService:
         # the swap inside the lock are each atomic under the GIL
         self._flush_lock = threading.Lock()
         self._flush_owner: int | None = None
+        self._flush_ids = itertools.count(1)  # the id a flush's spans share
         # stage the padded site arrays once per epoch, each site's rows
         # on the device of its mesh position; static per placement
         self._device_arrays = self.plan_store.site_device_arrays(
@@ -369,11 +376,29 @@ class QueryService:
     # -- planning -----------------------------------------------------------
 
     def _plan(self, req: _Request) -> None:
+        with spans.span("plan", request=req.ticket.id) as sp:
+            key = plancache.canonical_key(req.ast)
+            entry = self.plan_cache.get(key, self.stats_epoch)
+            req.plan_cache_hit = entry is not None
+            sp.count("hit", int(req.plan_cache_hit))
+            if entry is None:
+                entry = self._plan_entry(req, key)
+                self.plan_cache.put(key, self.stats_epoch, entry)
+            req.entry = entry
+            req.fkey = entry.fkey
+            req.label_mask = entry.label_mask
+            # pairs and witness requests resolve distinct signatures (the
+            # witness executor's carry is one f32 plane wider), so they
+            # batch into separate lanes and executor-cache slots
+            req.sig = entry.sig_witness if req.semantics == "witness" else entry.sig
+            with spans.span("plan.decide"):
+                self._decide(req)
+
+    def _plan_entry(self, req: _Request, key: tuple) -> plancache.PlanEntry:
+        """A plan-cache miss: the §5 rollout estimation, then the
+        automaton and everything the executors are keyed on."""
         cfg = self.config
-        key = plancache.canonical_key(req.ast)
-        entry = self.plan_cache.get(key, self.stats_epoch)
-        req.plan_cache_hit = entry is not None
-        if entry is None:
+        with spans.span("plan.estimate") as sp:
             est = planner.estimate_query(
                 req.query,
                 self.sample,
@@ -382,6 +407,8 @@ class QueryService:
                 n_rollouts=cfg.n_rollouts,
                 seed=cfg.seed,
             )
+            sp.count("rollouts", cfg.n_rollouts)
+        with spans.span("plan.compile"):
             ca = paa.compile_query(req.query, self.placement.graph)
             # query-class fast paths: closure queries run a reduced
             # 1-state automaton (no automaton product), single-label /
@@ -402,7 +429,7 @@ class QueryService:
                 cfg.site_axes, cfg.batch_axis, exec_levels,
                 cfg.s2_backend, cfg.s2_block_size,
             )
-            entry = plancache.PlanEntry(
+            return plancache.PlanEntry(
                 key=key, ast=req.ast, ca=ca, estimates=est,
                 fkey=feedback.label_class_key(req.ast),
                 label_mask=strategies.query_label_mask(req.ast, self.placement.graph),
@@ -419,17 +446,14 @@ class QueryService:
                     *sig_args, semantics="witness", tile_dtype="f32"
                 ),
             )
-            self.plan_cache.put(key, self.stats_epoch, entry)
-        req.entry = entry
-        req.fkey = entry.fkey
-        req.label_mask = entry.label_mask
-        # pairs and witness requests resolve distinct signatures (the
-        # witness executor's carry is one f32 plane wider), so they batch
-        # into separate lanes and executor-cache slots
-        req.sig = entry.sig_witness if req.semantics == "witness" else entry.sig
+
+    def _decide(self, req: _Request) -> None:
+        """The §6 decision at the calibrator's current factors, and the
+        cost forecast that sizes the async layer's batching windows."""
+        cfg = self.config
         f = self.calibrator.factors(req.fkey)
         plan = planner.decide_strategy(
-            entry.estimates,
+            req.entry.estimates,
             self.net,
             quantiles=cfg.quantiles,
             decision_quantile=cfg.decision_quantile,
@@ -477,19 +501,22 @@ class QueryService:
                 # the executor from exactly those
                 g_sem = group[0].semantics
                 g_levels = group[0].exec_max_levels
-                _, step_fn = self.exec_cache.get_or_build(
-                    group[0].exec_ca, self.placement.graph.n_nodes, self.mesh,
-                    cfg.site_axes, cfg.batch_axis, g_levels,
-                    signature=group[0].sig,
-                    backend=cfg.s2_backend, graph=self.placement.graph,
-                    replication_factor=self.placement.replication_factor,
-                    block_size=cfg.s2_block_size, placement=self.placement,
-                    stats_epoch=self.stats_epoch,
-                    bucket_floor=cfg.s2_bucket_floor,
-                    semantics=g_sem,
-                    tile_dtype=cfg.s2_tile_dtype,
-                    tile_store_budget_bytes=cfg.tile_store_budget_bytes,
-                )
+                with spans.span("s2.executor") as sp:
+                    builds = self.exec_cache.builds
+                    _, step_fn = self.exec_cache.get_or_build(
+                        group[0].exec_ca, self.placement.graph.n_nodes, self.mesh,
+                        cfg.site_axes, cfg.batch_axis, g_levels,
+                        signature=group[0].sig,
+                        backend=cfg.s2_backend, graph=self.placement.graph,
+                        replication_factor=self.placement.replication_factor,
+                        block_size=cfg.s2_block_size, placement=self.placement,
+                        stats_epoch=self.stats_epoch,
+                        bucket_floor=cfg.s2_bucket_floor,
+                        semantics=g_sem,
+                        tile_dtype=cfg.s2_tile_dtype,
+                        tile_store_budget_bytes=cfg.tile_store_budget_bytes,
+                    )
+                    sp.count("built", self.exec_cache.builds - builds)
 
                 def execute(starts, exemplar):
                     return strategies.s2_execute(
@@ -508,10 +535,16 @@ class QueryService:
                 continue
             for req in group:
                 rows, costs, batch, levels = results[id(req)]
-                answers = [set(np.nonzero(rows[i])[0].tolist()) for i in range(len(req.starts))]
-                for c in costs:
-                    self.calibrator.observe(req.fkey, req.entry.estimates, req.plan, c)
-                self._finish(req, answers, costs, exec_batch=batch, levels=levels)
+                rid = req.ticket.id
+                with spans.span("s2.answers", request=rid) as sp:
+                    answers = [set(np.nonzero(rows[i])[0].tolist()) for i in range(len(req.starts))]
+                    sp.count("starts", len(req.starts))
+                with spans.span("s2.calibrate", request=rid) as sp:
+                    for c in costs:
+                        self.calibrator.observe(req.fkey, req.entry.estimates, req.plan, c)
+                    sp.count("observations", len(costs))
+                with spans.span("s2.finish", request=rid):
+                    self._finish(req, answers, costs, exec_batch=batch, levels=levels)
 
     def _run_s1(self, reqs: list[_Request]) -> None:
         cfg = self.config
@@ -529,29 +562,31 @@ class QueryService:
                 continue
             for req in group:
                 try:
-                    ids = set(np.nonzero(req.label_mask)[0].tolist())
-                    own = sub if len(ids) == graph.n_labels else sub.subgraph_with_labels(ids)
-                    dg = paa.device_form(own)
-                    answers = [
-                        set(np.nonzero(np.asarray(paa.answers_single_source(req.ca, dg, int(s))))[0].tolist())
-                        for s in req.starts
-                    ]
-                    levels = None
-                    if req.semantics == "witness":
-                        # S1 answers locally: the collected subgraph holds
-                        # every edge the query can traverse, so its BFS
-                        # levels are valid against the global label store
-                        # (subgraph edges ⊆ global edges)
-                        idx = paa.HostIndex(own)
-                        levels = np.stack([
-                            witness.host_levels(
-                                req.exec_ca, idx, int(s),
-                                max_levels=req.exec_max_levels,
-                            )
+                    with spans.span("s1.answer", request=req.ticket.id) as sp:
+                        ids = set(np.nonzero(req.label_mask)[0].tolist())
+                        own = sub if len(ids) == graph.n_labels else sub.subgraph_with_labels(ids)
+                        dg = paa.device_form(own)
+                        answers = [
+                            set(np.nonzero(np.asarray(paa.answers_single_source(req.ca, dg, int(s))))[0].tolist())
                             for s in req.starts
-                        ]) if len(req.starts) else np.zeros(
-                            (0, req.exec_ca.n_states, graph.n_nodes), np.float32
-                        )
+                        ]
+                        sp.count("starts", len(req.starts))
+                        levels = None
+                        if req.semantics == "witness":
+                            # S1 answers locally: the collected subgraph holds
+                            # every edge the query can traverse, so its BFS
+                            # levels are valid against the global label store
+                            # (subgraph edges ⊆ global edges)
+                            idx = paa.HostIndex(own)
+                            levels = np.stack([
+                                witness.host_levels(
+                                    req.exec_ca, idx, int(s),
+                                    max_levels=req.exec_max_levels,
+                                )
+                                for s in req.starts
+                            ]) if len(req.starts) else np.zeros(
+                                (0, req.exec_ca.n_states, graph.n_nodes), np.float32
+                            )
                 except Exception as e:  # noqa: BLE001
                     self._fail(req, e)
                     continue
@@ -652,27 +687,25 @@ class QueryService:
 
     def _flush_locked(self) -> list[Ticket]:
         pending, self._queue = self._queue, []
-        planned: list[_Request] = []
-        for req in pending:
-            try:
-                if req.plan is None:  # plan_request() tickets arrive planned
-                    self._plan(req)
-                planned.append(req)
-            except Exception as e:  # noqa: BLE001
-                self._fail(req, e)
-        s2 = [r for r in planned if r.strategy == "S2"]
-        s1 = [r for r in planned if r.strategy != "S2"]
-        if s2:
-            self._run_s2(s2)
-        if s1:
-            self._run_s1(s1)
-        # surface the two-stage-compilation counters in the flush stats
-        self.metrics.set_cache_stats(
-            exec_cache=self.exec_cache.stats(),
-            plan_store=self.plan_store.stats(),
-            plan_pad_waste=self.plan_store.pad_stats(),
-            frontier_mem=self.exec_cache.frontier_mem_stats(),
-        )
+        with spans.span("flush", flush=next(self._flush_ids)) as sp:
+            if sp:
+                sp.attrs["tickets"] = [r.ticket.id for r in pending]
+                sp.count("requests", len(pending))
+                sp.count("starts", sum(len(r.starts) for r in pending))
+            planned: list[_Request] = []
+            for req in pending:
+                try:
+                    if req.plan is None:  # plan_request() tickets arrive planned
+                        self._plan(req)
+                    planned.append(req)
+                except Exception as e:  # noqa: BLE001
+                    self._fail(req, e)
+            s2 = [r for r in planned if r.strategy == "S2"]
+            s1 = [r for r in planned if r.strategy != "S2"]
+            if s2:
+                self._run_s2(s2)
+            if s1:
+                self._run_s1(s1)
         return [r.ticket for r in pending]
 
     # -- Stage-A persistence (warm restarts) ---------------------------------
