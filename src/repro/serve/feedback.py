@@ -71,7 +71,7 @@ class Calibrator:
 
     def _update(self, key: tuple, channel: str, target: float) -> None:
         lo, hi = self.clamp
-        target = float(np.clip(target, lo, hi))
+        target = min(max(target, lo), hi)
         slot = self._factors.setdefault(key, {})
         prev = slot.get(channel, 1.0)
         slot[channel] = (1.0 - self.decay) * prev + self.decay * target
@@ -83,25 +83,41 @@ class Calibrator:
         plan: planner.QueryPlan,
         observed: StrategyCost,
     ) -> None:
-        """Fold one execution's observed cost back into the factors.
+        """Fold one execution's observed cost back into the factors."""
+        self.observe_many(key, estimates, plan, [observed])
+
+    def observe_many(
+        self,
+        key: tuple,
+        estimates: planner.PlanEstimates,
+        plan: planner.QueryPlan,
+        costs: list[StrategyCost],
+    ) -> int:
+        """Fold a request's observed costs back into the factors, one EWMA
+        step per cost, in order; returns the forecasts computed (0 or 1).
 
         Ratios are taken against the *raw* (un-calibrated) estimates in
-        ``estimates``, at the plan's decision quantile for S2.
+        ``estimates``, at the plan's decision quantile for S2.  That
+        forecast depends only on ``estimates`` and ``plan``, so it is
+        computed once for all of the request's S2 costs.
         """
-        self.n_observations += 1
-        if observed.strategy == "S1":
-            if estimates.d_s1 > 0 and observed.unicast_symbols > 0:
-                self._update(key, "d_s1", observed.unicast_symbols / estimates.d_s1)
-            return
-        # S2: compare against the raw decision-quantile forecast
-        _, q_bc_raw, d_s2_raw = planner.calibrated_samples(estimates)
-        dq = plan.decision_quantile
-        q_bc_fc = float(np.quantile(q_bc_raw, dq))
-        d_s2_fc = float(np.quantile(d_s2_raw, dq))
-        if q_bc_fc > 0 and observed.broadcast_symbols > 0:
-            self._update(key, "q_bc", observed.broadcast_symbols / q_bc_fc)
-        if d_s2_fc > 0 and observed.unicast_symbols > 0:
-            self._update(key, "d_s2", observed.unicast_symbols / d_s2_fc)
+        forecast = None
+        for observed in costs:
+            self.n_observations += 1
+            if observed.strategy == "S1":
+                if estimates.d_s1 > 0 and observed.unicast_symbols > 0:
+                    self._update(key, "d_s1", observed.unicast_symbols / estimates.d_s1)
+                continue
+            if forecast is None:
+                _, q_bc_raw, d_s2_raw = planner.calibrated_samples(estimates)
+                dq = plan.decision_quantile
+                forecast = (float(np.quantile(q_bc_raw, dq)), float(np.quantile(d_s2_raw, dq)))
+            q_bc_fc, d_s2_fc = forecast
+            if q_bc_fc > 0 and observed.broadcast_symbols > 0:
+                self._update(key, "q_bc", observed.broadcast_symbols / q_bc_fc)
+            if d_s2_fc > 0 and observed.unicast_symbols > 0:
+                self._update(key, "d_s2", observed.unicast_symbols / d_s2_fc)
+        return int(forecast is not None)
 
     # -- reporting ----------------------------------------------------------
 

@@ -540,9 +540,11 @@ class QueryService:
                     answers = [set(np.nonzero(rows[i])[0].tolist()) for i in range(len(req.starts))]
                     sp.count("starts", len(req.starts))
                 with spans.span("s2.calibrate", request=rid) as sp:
-                    for c in costs:
-                        self.calibrator.observe(req.fkey, req.entry.estimates, req.plan, c)
+                    forecasts = self.calibrator.observe_many(
+                        req.fkey, req.entry.estimates, req.plan, costs
+                    )
                     sp.count("observations", len(costs))
+                    sp.count("forecasts", forecasts)
                 with spans.span("s2.finish", request=rid):
                     self._finish(req, answers, costs, exec_batch=batch, levels=levels)
 

@@ -491,6 +491,73 @@ def test_calibrator_clamps_pathological_ratios():
     assert cal.factors(key).d_s1 == 5.0
 
 
+def _observe_per_cost(cal, key, est, plan, costs):
+    """The per-cost arithmetic of one ``observe`` a cost, forecast and
+    ``np.clip`` included: what ``observe_many`` must reproduce exactly."""
+    slot = cal._factors.setdefault(key, {})
+
+    def update(channel, target):
+        prev = slot.get(channel, 1.0)
+        slot[channel] = (1.0 - cal.decay) * prev + cal.decay * float(np.clip(target, *cal.clamp))
+
+    for c in costs:
+        cal.n_observations += 1
+        if c.strategy == "S1":
+            if est.d_s1 > 0 and c.unicast_symbols > 0:
+                update("d_s1", c.unicast_symbols / est.d_s1)
+            continue
+        _, q_bc_raw, d_s2_raw = planner.calibrated_samples(est)
+        q_bc_fc = float(np.quantile(q_bc_raw, plan.decision_quantile))
+        d_s2_fc = float(np.quantile(d_s2_raw, plan.decision_quantile))
+        if q_bc_fc > 0 and c.broadcast_symbols > 0:
+            update("q_bc", c.broadcast_symbols / q_bc_fc)
+        if d_s2_fc > 0 and c.unicast_symbols > 0:
+            update("d_s2", c.unicast_symbols / d_s2_fc)
+
+
+_Cost = strategies.StrategyCost
+
+
+@pytest.mark.parametrize("q_bc_samples, costs, forecasts", [
+    pytest.param(  # mixed ratios
+        np.linspace(1.0, 40.0, 600),
+        [_Cost("S2", 3.0, 70.0), _Cost("S2", 41.7, 0.3), _Cost("S2", 12.0, 12.0), _Cost("S2", 0.9, 1e3)],
+        1, id="s2-mixed"),
+    pytest.param(  # far above and far below the clamp
+        np.linspace(1.0, 40.0, 600),
+        [_Cost("S2", 1e9, 1e-6), _Cost("S2", 1e-6, 1e9), _Cost("S2", 1e9, 1e9), _Cost("S2", 7.0, 7.0)],
+        1, id="s2-clamped"),
+    pytest.param(  # a channel skipped when its observed symbols are zero
+        np.linspace(1.0, 40.0, 600),
+        [_Cost("S2", 0.0, 25.0), _Cost("S2", 19.0, 0.0), _Cost("S2", 0.0, 0.0), _Cost("S2", 5.0, 6.0)],
+        1, id="s2-zero-symbols"),
+    pytest.param(  # no rollout broadcasts: the unfiltered samples, q_bc skipped
+        np.zeros(600),
+        [_Cost("S2", 4.0, 30.0), _Cost("S2", 9.0, 2.0)],
+        1, id="s2-all-zero-q_bc"),
+    pytest.param(
+        np.linspace(1.0, 40.0, 600), [_Cost("S1", 2.0, 310.0)], 0, id="s1"),
+    pytest.param(np.linspace(1.0, 40.0, 600), [], 0, id="empty"),
+])
+def test_observe_many_equals_one_observation_per_cost(q_bc_samples, costs, forecasts):
+    key = (("a", "b"), False)
+    rng = np.random.default_rng(7)
+    est = planner.PlanEstimates(
+        query="a b", q_lbl=2.0, d_s1=120.0,
+        q_bc_samples=q_bc_samples, d_s2_samples=rng.uniform(0.0, 200.0, 600),
+        wildcard=False,
+    )
+    plan = planner.decide_strategy(est, NET)
+    got, want = Calibrator(decay=0.3), Calibrator(decay=0.3)
+    for cal in (got, want):  # start from factors an earlier request left
+        cal._factors[key] = {"d_s1": 1.7, "q_bc": 0.45, "d_s2": 3.1}
+        cal.n_observations = 5
+    assert got.observe_many(key, est, plan, costs) == forecasts
+    _observe_per_cost(want, key, est, plan, costs)
+    assert got._factors == want._factors
+    assert got.n_observations == want.n_observations == 5 + len(costs)
+
+
 def test_service_feedback_loop_runs(setup, service):
     """After serving, the calibrator holds factors for the seen classes
     and they reflect observed/forecast (finite, clamped, not all 1)."""

@@ -193,6 +193,7 @@ def test_s2_flush_span_tree(placement, recording):
     starts = {r.request: r.counters["starts"] for r in names["s2.answers"]}
     assert starts == {t1.id: 3, t2.id: 2}
     assert {r.request: r.counters["observations"] for r in names["s2.calibrate"]} == starts
+    assert all(r.counters["forecasts"] == 1 for r in names["s2.calibrate"])  # one per request
     assert t1.result().answers and t2.done
 
 
