@@ -131,7 +131,7 @@ def run(
         "n_labels": n_labels,
         "block_size": block,
         "n_transitions": len(ca.transitions),
-        "grid_steps_fused": int(np.asarray(plan.tile_ids).shape[0]),
+        "grid_steps_fused": int(np.asarray(plan.step).shape[0]),
         "dispatches_per_level_fused": disp_fused,
         "dispatches_per_level_baseline": disp_base,
         # the fused level carries QPAD stacked queries per call, the

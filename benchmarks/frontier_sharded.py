@@ -99,7 +99,7 @@ def run(
         "n_labels": n_labels,
         "block_size": block,
         "batch": len(starts),
-        "grid_steps_global": int(np.asarray(global_plan.tile_ids).shape[0]),
+        "grid_steps_global": int(np.asarray(global_plan.step).shape[0]),
         "sites": {},
     }
 

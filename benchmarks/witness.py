@@ -157,8 +157,8 @@ def run(
             "query": CLOSURE_QUERY,
             "n_states_general": ca_gen.n_states,
             "n_states_fast": ca_fast.n_states,
-            "grid_steps_general": int(np.asarray(plan_gen.tile_ids).shape[0]),
-            "grid_steps_fast": int(np.asarray(plan_fast.tile_ids).shape[0]),
+            "grid_steps_general": int(np.asarray(plan_gen.step).shape[0]),
+            "grid_steps_fast": int(np.asarray(plan_fast.step).shape[0]),
             "fixpoint_ms_closure_general": 1e3 * t_gen,
             "fixpoint_ms_closure_fastpath": 1e3 * t_fast,
             "speedup_x": t_gen / t_fast,

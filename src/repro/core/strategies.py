@@ -720,21 +720,28 @@ def _fetch_staged_graph(
     lives in the :class:`~repro.core.plans.GraphPlanStore`."""
     from repro.kernels.frontier import ops as fops
 
-    if plan_store is not None:
-        if budget_bytes is not None:
-            return plan_store.staged_graph(
-                graph, block_size, epoch=stats_epoch, tile_dtype=tile_dtype,
-                budget_bytes=budget_bytes, keys=fops.required_offset_keys(ca),
-            )
-        return plan_store.staged_graph(
-            graph, block_size, epoch=stats_epoch, tile_dtype=tile_dtype
-        )
-    if budget_bytes is not None:
+    if budget_bytes is not None and plan_store is None:
         raise ValueError(
             "tile_store_budget_bytes requires plan_store= (the out-of-core "
             "slab cache lives in the GraphPlanStore)"
         )
-    return fops.stage_graph(graph, block_size, tile_dtype=tile_dtype)
+    with spans.span("s2.stage") as sp:
+        if plan_store is None:
+            staged = fops.stage_graph(graph, block_size, tile_dtype=tile_dtype)
+        elif budget_bytes is not None:
+            staged = plan_store.staged_graph(
+                graph, block_size, epoch=stats_epoch, tile_dtype=tile_dtype,
+                budget_bytes=budget_bytes, keys=fops.required_offset_keys(ca),
+            )
+        else:
+            staged = plan_store.staged_graph(
+                graph, block_size, epoch=stats_epoch, tile_dtype=tile_dtype
+            )
+        if sp:
+            sp.count("tiles", staged.n_tiles)
+            sp.count("raw_bytes", staged.tile_store_bytes)
+            sp.count("store_bytes", staged.store_bytes)
+    return staged
 
 
 def _make_frontier_step_fn(
@@ -806,7 +813,7 @@ def _make_frontier_step_fn(
     state_rows = [jnp.asarray(states, jnp.int32) for _, states in sgroups]
 
     def fixpoint(f0, operands):  # (n_states, q_pad, v_pad) f32 0/1
-        sched, deg_c, pay_c = operands[:8], operands[8], operands[9]
+        sched, deg_c, pay_c = operands[:4], operands[4], operands[5]
         flat0 = f0.reshape(n_states * q_pad, v_pad)
         zero_q = jnp.zeros((q_pad,), jnp.float32)
 
@@ -895,7 +902,7 @@ def _make_frontier_step_fn(
         return res
 
     # retrieval is modeled on the staged global tiles
-    return _bind_operands(run, operands, interpret)
+    return _bind_operands(run, operands, interpret, store_bytes=staged.store_bytes)
 
 
 def _make_frontier_packed_step_fn(
@@ -981,7 +988,7 @@ def _make_frontier_packed_step_fn(
         return bits.reshape(n_states, q_pack, v_pad)
 
     def fixpoint(f0, operands):  # (n_states, q_pad, v_pad) uint32 lane words
-        sched, deg_c, pay_c = operands[:8], operands[8], operands[9]
+        sched, deg_c, pay_c = operands[:4], operands[4], operands[5]
         flat0 = f0.reshape(n_states * q_pad, v_pad)
         zero_q = jnp.zeros((q_pack,), jnp.float32)
 
@@ -1084,20 +1091,22 @@ def _make_frontier_packed_step_fn(
         return res + (out[-1].sum(),)
 
     # retrieval is modeled on the staged global tiles
-    return _bind_operands(run, operands, interpret, kernel_bytes=plan.kernel_bytes)
+    return _bind_operands(
+        run, operands, interpret, kernel_bytes=plan.kernel_bytes, store_bytes=staged.store_bytes
+    )
 
 
 def _schedule_operands(plan) -> tuple:
     """A fused plan's tile store and Stage-B step arrays, in the order
     :func:`repro.kernels.frontier.frontier.fused_level_blocks` takes
     them (the same order for ``packed_level_blocks``)."""
-    return (
-        plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
-    )
+    return (plan.tiles, plan.step, plan.src, plan.dst)
 
 
-def _bind_operands(run, operands, interpret: bool, kernel_bytes: int | None = None):
+def _bind_operands(
+    run, operands, interpret: bool, kernel_bytes: int | None = None,
+    store_bytes: int | None = None,
+):
     """Wrap ``run(operands, starts)`` in the shared step contract
     ``fn(src, lbl, dst, mask, starts)`` of the fused executors; they
     ignore the site edge arrays and read only their staged operands.
@@ -1110,7 +1119,9 @@ def _bind_operands(run, operands, interpret: bool, kernel_bytes: int | None = No
     ``fn.clear_cache`` drops the compiled programs on eviction.  Where
     ``kernel_bytes`` (the level kernel's HBM bytes per call) is given,
     ``run``'s last output is the number of level-kernel calls it made,
-    and ``fn.kernel_bytes`` says so to :func:`s2_execute`."""
+    and ``fn.kernel_bytes`` says so to :func:`s2_execute`;
+    ``fn.store_bytes`` is the device bytes of the tile store its kernel
+    reads."""
     jitted = jax.jit(run)
 
     def fn(src, lbl, dst, mask, starts):
@@ -1120,6 +1131,7 @@ def _bind_operands(run, operands, interpret: bool, kernel_bytes: int | None = No
     fn.clear_cache = jitted.clear_cache
     fn.interpret = interpret
     fn.kernel_bytes = kernel_bytes
+    fn.store_bytes = store_bytes
     return fn
 
 
@@ -1204,8 +1216,8 @@ def _make_frontier_sharded_step_fn(
         local expansion   — per shape bucket, one (vmapped)
                             ``fused_level_blocks`` call over this
                             device's member sites (padding steps
-                            early-out in-kernel via the ``valids``
-                            prefetch flag),
+                            early-out in-kernel via the step word's
+                            ``valid`` bit),
         frontier exchange — a double-buffered ring: each iteration
                             ``lax.ppermute`` forwards the *previous*
                             iteration's discoveries one hop along each
@@ -1312,32 +1324,28 @@ def _make_frontier_sharded_step_fn(
     state_rows = [jnp.asarray(states, jnp.int32) for _, states in sgroups]
 
     def local(*ops):
-        # ops = 8 arrays per bucket (leading dim = this device's member
+        # ops = 4 arrays per bucket (leading dim = this device's member
         # sites of that bucket), then deg_l, starts
-        bucket_ops = [ops[i * 8 : (i + 1) * 8] for i in range(n_buckets)]
+        bucket_ops = [ops[i * 4 : (i + 1) * 4] for i in range(n_buckets)]
         deg_l, starts = ops[-2], ops[-1]
         s_local = deg_l.shape[0]
 
         def expand(frontier):  # (n_states * q_pad, v_pad) -> same, {0,1}
             fre = fops.extend_frontier(frontier, union_members, n_states, q_pad)
             merged = jnp.zeros((n_states * q_pad, v_pad), jnp.float32)
-            for tiles, fi, vl, ti, fr, fc, orw, oc in bucket_ops:
+            for tiles, step, src, dst in bucket_ops:
                 if tiles.shape[0] == 1:
                     counts = fkernel.fused_level_blocks(
-                        fre, tiles[0], fi[0], vl[0], ti[0], fr[0], fc[0],
-                        orw[0], oc[0], plan.block_size, q_pad,
+                        fre, tiles[0], step[0], src[0], dst[0], plan.block_size, q_pad,
                         interpret=interpret, n_out_rows=n_states * q_pad,
                     )
                 else:  # all of this bucket's local sites in ONE vmapped call
                     counts = jax.vmap(
-                        lambda t, fi_, vl_, ti_, fr_, fc_, orw_, oc_: (
-                            fkernel.fused_level_blocks(
-                                fre, t, fi_, vl_, ti_, fr_, fc_, orw_, oc_,
-                                plan.block_size, q_pad, interpret=interpret,
-                                n_out_rows=n_states * q_pad,
-                            )
+                        lambda t, st, sr, ds: fkernel.fused_level_blocks(
+                            fre, t, st, sr, ds, plan.block_size, q_pad,
+                            interpret=interpret, n_out_rows=n_states * q_pad,
                         )
-                    )(tiles, fi, vl, ti, fr, fc, orw, oc).max(axis=0)
+                    )(tiles, step, src, dst).max(axis=0)
                 merged = jnp.maximum(merged, counts)
             return jnp.minimum(merged, 1.0)
 
@@ -1473,14 +1481,11 @@ def _make_frontier_sharded_step_fn(
     spec_b = P(b_ax) if b_ax else P()
     bucket_args, bucket_specs = [], []
     for bk in plan.buckets:
-        bucket_args += [
-            bk.tiles, bk.firsts, bk.valids, bk.tile_ids,
-            bk.f_rows, bk.f_cols, bk.o_rows, bk.o_cols,
-        ]
+        bucket_args += [bk.tiles, bk.step, bk.src, bk.dst]
         # tiles (rows, n_tiles, B, B); step arrays (rows, n_steps) — rows
         # is device-major, so sharding it over site_axes hands each
         # device exactly its member sites of this bucket
-        bucket_specs += [spec_s(3)] + [spec_s(1)] * 7
+        bucket_specs += [spec_s(3)] + [spec_s(1)] * 3
     out_specs = (
         P(b_ax, None) if b_ax else P(None, None),
         spec_b, spec_b, spec_b,
@@ -1513,7 +1518,8 @@ def _make_frontier_sharded_step_fn(
         return sharded(*operands, starts)
 
     # retrieval runs on the staged per-site tiles
-    return _bind_operands(run, operands, interpret)
+    store_bytes = sum(fops.device_bytes(b.tiles) for b in tile_buckets.buckets)
+    return _bind_operands(run, operands, interpret, store_bytes=store_bytes)
 
 
 def s2_execute(
@@ -1625,6 +1631,9 @@ def s2_execute(
             if kernel_bytes is not None:
                 sp.count("levels", int(n_calls))
                 sp.count("kernel_bytes", kernel_bytes)
+            store_bytes = getattr(step_fn, "store_bytes", None)
+            if store_bytes is not None:
+                sp.count("store_bytes", store_bytes)
     k_rep = max(placement.replication_factor, 1e-9)
     costs = [
         StrategyCost(

@@ -58,13 +58,16 @@ scalar-prefetch schedule (``firsts`` zero-init, ``valids`` early-out,
 sorted (o_row, o_col) steps) is shared verbatim with the f32 kernel.
 
 Both entry points also accept a **bitpacked tile store**: when
-``tiles`` is uint32 (n_tiles, B, ceil(B/32)) the dst axis is packed
-into bit-planes (``ref.pack_blocks(tile_dtype="uint32")`` — the same
-word layout as the frontier lanes) and the ``*_u32`` kernel variants
-unpack each tile's bits in-register.  The f32-frontier variant then
-runs the same MXU dot on the recovered {0,1} matrix; the packed-frontier
-variant is pure bitwise AND/OR end to end — no in-kernel f32 threshold,
-no popcounts — at 1/32 the tile-store HBM traffic per step.
+``tiles`` is uint32 (n_tiles, ceil(B/32), B) the src axis is packed
+into bit-planes along the rows and the dst axis runs along the lanes
+(``ref.pack_blocks(tile_dtype="uint32")``), and the ``*_u32`` kernel
+variants unpack each tile's bits in-register.  The store is lane-dense:
+its (1, ceil(B/32), B) tile block (:func:`tile_block_shape`) is the
+array's own layout, so the executor hands it to the kernel without a
+relayout copy.  The f32-frontier variant then runs the same MXU dot on
+the recovered {0,1} matrix; the packed-frontier variant is pure bitwise
+AND/OR end to end — no in-kernel f32 threshold, no popcounts — at 1/32
+the tile-store HBM traffic per step.
 """
 
 from __future__ import annotations
@@ -73,6 +76,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -154,76 +158,175 @@ def frontier_step_blocks(
     )(block_rows, block_cols, frontier, tiles)
 
 
-def _unpack_tile_bits(words: jax.Array, block_size: int) -> jax.Array:
+def tile_block_shape(tiles: jax.Array) -> tuple[int, int, int]:
+    """The tile operand's block in both level kernels: one whole tile,
+    (1, B, B) f32 or (1, ceil(B/32), B) uint32.  ``ops.level_kernel_bytes``
+    counts this block's bytes for each tile fetch."""
+    return (1, int(tiles.shape[-2]), int(tiles.shape[-1]))
+
+
+def _unpack_tile_bits(a_ref, block_size: int) -> jax.Array:
     """In-kernel inverse of the ``tile_dtype="uint32"`` bit-plane packing:
-    a (B, W) uint32 word block back to the (B, B) bool adjacency — dst
-    ``d`` is bit ``d % 32`` of word ``d // 32``.  Pure VPU shifts on an
-    iota, no gathers; the bit axis expands W words to W·32 columns and
-    the slice drops the pad when B is not a multiple of 32."""
-    b, w = words.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (b, w, 32), 2)
-    bits = (words[:, :, None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(b, w * 32)[:, :block_size] != 0
+    a (1, W, B) uint32 tile block back to the (B, B) bool adjacency —
+    edge ``(v, j)`` is bit ``v % 32`` of word row ``v // 32`` at lane
+    ``j``.  Each word row broadcasts down 32 sublanes and shifts by the
+    sublane index: pure VPU work on whole lane rows, no gathers; the
+    slice drops the pad rows when B is not a multiple of 32."""
+    w = a_ref.shape[1]
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (32, block_size), 0)
+    planes = [
+        (jnp.broadcast_to(a_ref[0, u : u + 1, :], (32, block_size)) >> shifts)
+        & jnp.uint32(1)
+        for u in range(w)
+    ]
+    bits = planes[0] if w == 1 else jnp.concatenate(planes, axis=0)
+    return bits[:block_size] != 0
 
 
-def _fused_level_kernel(
-    firsts_ref, valids_ref, tids_ref, frows_ref, fcols_ref, orows_ref, ocols_ref,
-    f_ref, a_ref, o_ref,
-):
+# The level kernels' schedule rides in SMEM as scalar prefetch, three
+# int32 words a grid step (SMEM holds 1 MiB on a v5e: seven words a step
+# would cap a level near 37k steps, three hold about 87k):
+#   word 0: tile id | valid << 30 | first << 31
+#   word 1: frontier row-block << 16 | frontier col-block
+#   word 2: output row-block << 16 | output col-block
+_TILE_BITS = 30
+_COL_BITS = 16
+_COL_MASK = (1 << _COL_BITS) - 1
+
+
+_ROW_BITS = 31 - _COL_BITS
+
+
+def pack_schedule(
+    firsts: np.ndarray, valids: np.ndarray, tile_ids: np.ndarray, f_rows: np.ndarray,
+    f_cols: np.ndarray, o_rows: np.ndarray, o_cols: np.ndarray,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Pack a Stage-B schedule's per-step columns, on the host, into the
+    three int32 step words (``step``, ``src``, ``dst``) that the level
+    kernels prefetch (the layout above)."""
+    cols = [np.asarray(a, np.int64) for a in
+            (firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols)]
+    firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols = cols
+    limits = ((tile_ids, _TILE_BITS), (f_rows, _ROW_BITS), (o_rows, _ROW_BITS),
+              (f_cols, _COL_BITS), (o_cols, _COL_BITS))
+    for col, bits in limits:
+        if col.size and (col.min() < 0 or col.max() >= 1 << bits):
+            raise ValueError(f"a schedule entry of {int(col.max())} does not fit {bits} bits")
+    step = tile_ids | (valids << _TILE_BITS) | (firsts << (_TILE_BITS + 1))
+    src = (f_rows << _COL_BITS) | f_cols
+    dst = (o_rows << _COL_BITS) | o_cols
+    return tuple(w.astype(np.uint32).view(np.int32) for w in (step, src, dst))
+
+
+def unpack_schedule(step, src, dst) -> dict[str, np.ndarray]:
+    """The inverse of :func:`pack_schedule`: the per-step columns by name."""
+    step, src, dst = (np.asarray(w).view(np.uint32) for w in (step, src, dst))
+    return {
+        "firsts": (step >> (_TILE_BITS + 1)).astype(np.int32),
+        "valids": ((step >> _TILE_BITS) & 1).astype(np.int32),
+        "tile_ids": (step & ((1 << _TILE_BITS) - 1)).astype(np.int32),
+        "f_rows": (src >> _COL_BITS).astype(np.int32),
+        "f_cols": (src & _COL_MASK).astype(np.int32),
+        "o_rows": (dst >> _COL_BITS).astype(np.int32),
+        "o_cols": (dst & _COL_MASK).astype(np.int32),
+    }
+
+
+def _first(step_ref, i):
+    return ((step_ref[i] >> (_TILE_BITS + 1)) & 1) == 1
+
+
+def _valid(step_ref, i):
+    return ((step_ref[i] >> _TILE_BITS) & 1) == 1
+
+
+def _fused_level_kernel(step_ref, src_ref, dst_ref, f_ref, a_ref, o_ref):
     """One grid step of the fused level:
 
         o[dst_state, :, ocol] += f[frow, :, fcol] @ tiles[tid]
 
     where the middle dim is the q_pad stacked-query rows and ``frow`` may
     address a virtual fan-in union row past the automaton states.
-    ``firsts`` is precomputed on the host (steps are sorted by
+    ``first`` is precomputed on the host (steps are sorted by
     (dst_state, block_col), so the first step of each output block is
     known statically) — it gates the zero-init of the output block before
-    accumulation.  ``valids`` gates the tile product itself: cover and
-    shape-class padding steps (``valids=0``) early-out after the
+    accumulation.  ``valid`` gates the tile product itself: cover and
+    shape-class padding steps (``valid=0``) early-out after the
     predicate instead of multiplying the zero tile."""
     i = pl.program_id(0)
 
-    @pl.when(firsts_ref[i] == 1)
+    @pl.when(_first(step_ref, i))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(valids_ref[i] == 1)
+    @pl.when(_valid(step_ref, i))
     def _accumulate():
         o_ref[...] += jnp.dot(f_ref[...], a_ref[0], preferred_element_type=jnp.float32)
 
 
-def _fused_level_kernel_u32(
-    firsts_ref, valids_ref, tids_ref, frows_ref, fcols_ref, orows_ref, ocols_ref,
-    f_ref, a_ref, o_ref, *, block_size,
-):
+def _fused_level_kernel_u32(step_ref, src_ref, dst_ref, f_ref, a_ref, o_ref, *, block_size):
     """:func:`_fused_level_kernel` against a bitpacked uint32 tile store:
-    the (1, B, W) word block unpacks to the (B, B) 0/1 adjacency
+    the (1, W, B) word block unpacks to the (B, B) 0/1 adjacency
     in-register (:func:`_unpack_tile_bits`) and the accumulation is the
     same f32 MXU dot — counts and outputs are bit-exact vs the f32 tiles
     because both store exactly the same {0,1} adjacency."""
     i = pl.program_id(0)
 
-    @pl.when(firsts_ref[i] == 1)
+    @pl.when(_first(step_ref, i))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(valids_ref[i] == 1)
+    @pl.when(_valid(step_ref, i))
     def _accumulate():
-        a = _unpack_tile_bits(a_ref[0], block_size).astype(jnp.float32)
+        a = _unpack_tile_bits(a_ref, block_size).astype(jnp.float32)
         o_ref[...] += jnp.dot(f_ref[...], a, preferred_element_type=jnp.float32)
+
+
+def _level_call(kernel, name, out_dtype, frontier, tiles, step, src, dst, block_size, q_pad,
+                interpret, n_out_rows):
+    """The pallas_call both level kernels share: one grid step per step
+    word, the frontier and output blocks (q_pad, B) at the ``src`` and
+    ``dst`` words' row and column blocks, the tile block
+    (:func:`tile_block_shape`) at the ``step`` word's tile id."""
+    n_rows, v_pad = frontier.shape
+    if n_out_rows is None:
+        n_out_rows = n_rows
+
+    def rows(word):
+        return word >> _COL_BITS
+
+    def cols(word):
+        return word & _COL_MASK
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(step.shape[0],),
+        in_specs=[
+            pl.BlockSpec((q_pad, block_size), lambda i, st, sr, ds: (rows(sr[i]), cols(sr[i]))),
+            pl.BlockSpec(
+                tile_block_shape(tiles),
+                lambda i, st, sr, ds: (st[i] & ((1 << _TILE_BITS) - 1), 0, 0),
+            ),
+        ],
+        out_specs=pl.BlockSpec(
+            (q_pad, block_size), lambda i, st, sr, ds: (rows(ds[i]), cols(ds[i]))
+        ),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        name=name,
+        out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), out_dtype),
+        interpret=resolve_interpret(interpret),
+    )(step, src, dst, frontier, tiles)
 
 
 def fused_level_blocks(
     frontier: jax.Array,  # (n_rows * q_pad, v_pad) f32 0/1 (union rows appended)
     tiles: jax.Array,  # (n_tiles, B, B) f32 0/1; index 0 is the zero cover tile
-    firsts: jax.Array,  # (n_steps,) int32 ∈ {0,1}: first visit to the output block
-    valids: jax.Array,  # (n_steps,) int32 ∈ {0,1}: 0 = cover/padding, skip the dot
-    tile_ids: jax.Array,  # (n_steps,) int32 into tiles
-    f_rows: jax.Array,  # (n_steps,) int32: input row-block (state or union row)
-    f_cols: jax.Array,  # (n_steps,) int32: input col-block = tile block row
-    o_rows: jax.Array,  # (n_steps,) int32: output row-block = dst automaton state
-    o_cols: jax.Array,  # (n_steps,) int32: output col-block = tile block col
+    step: jax.Array,  # (n_steps,) int32 step words: tile id, valid, first
+    src: jax.Array,  # (n_steps,) int32: input row-block (state or union row), col-block
+    dst: jax.Array,  # (n_steps,) int32: output row-block (dst state), col-block
     block_size: int,
     q_pad: int,
     interpret: bool | None = None,
@@ -241,45 +344,18 @@ def fused_level_blocks(
     raw count matrix (n_out_rows, v_pad); callers threshold >0.
 
     ``tiles`` may be the f32 store (n_tiles, B, B) or the bitpacked
-    uint32 store (n_tiles, B, ceil(B/32)) — the kernel variant is picked
+    uint32 store (n_tiles, ceil(B/32), B) — the kernel variant is picked
     off the dtype and the packed tiles unpack in-register, so one
-    Stage-B schedule serves both tile stores.
+    Stage-B schedule serves both tile stores.  The schedule is the three
+    step words of :func:`pack_schedule`.
     """
-    n_rows, v_pad = frontier.shape
-    if n_out_rows is None:
-        n_out_rows = n_rows
-    n_steps = tile_ids.shape[0]
-    packed_tiles = tiles.dtype == jnp.uint32
     kernel = (
         partial(_fused_level_kernel_u32, block_size=block_size)
-        if packed_tiles
+        if tiles.dtype == jnp.uint32
         else _fused_level_kernel
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec(
-                (q_pad, block_size),
-                lambda i, fi, vl, ti, fr, fc, orw, oc: (fr[i], fc[i]),
-            ),
-            pl.BlockSpec(
-                (1, block_size, int(tiles.shape[2])),
-                lambda i, fi, vl, ti, fr, fc, orw, oc: (ti[i], 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (q_pad, block_size),
-            lambda i, fi, vl, ti, fr, fc, orw, oc: (orw[i], oc[i]),
-        ),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        name="rpq_fused_level",
-        out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols, frontier, tiles)
+    return _level_call(kernel, "rpq_fused_level", jnp.float32, frontier, tiles, step, src, dst,
+                       block_size, q_pad, interpret, n_out_rows)
 
 
 def _or_of_and(f: jax.Array, a: jax.Array) -> jax.Array:
@@ -299,10 +375,7 @@ def _or_of_and(f: jax.Array, a: jax.Array) -> jax.Array:
     return c[0]
 
 
-def _packed_level_kernel(
-    firsts_ref, valids_ref, tids_ref, frows_ref, fcols_ref, orows_ref, ocols_ref,
-    f_ref, a_ref, o_ref,
-):
+def _packed_level_kernel(step_ref, src_ref, dst_ref, f_ref, a_ref, o_ref):
     """One grid step of the bitpacked fused level:
 
         o[dst_state, :, ocol] |= OR-of-AND(f[frow, :, fcol], tiles[tid])
@@ -313,26 +386,23 @@ def _packed_level_kernel(
     boolean adjacency in-kernel, so one Stage-A staging serves both the
     f32 matmul and the packed kernel.  The OR-of-AND is
     :func:`_or_of_and`: lane words masked by the adjacency, OR-folded
-    over the contraction axis.  ``firsts`` /
-    ``valids`` keep the exact semantics of :func:`_fused_level_kernel`:
+    over the contraction axis.  ``first`` /
+    ``valid`` keep the exact semantics of :func:`_fused_level_kernel`:
     zero-init on the output block's first step, early-out on cover and
     shape-class padding steps."""
     i = pl.program_id(0)
 
-    @pl.when(firsts_ref[i] == 1)
+    @pl.when(_first(step_ref, i))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(valids_ref[i] == 1)
+    @pl.when(_valid(step_ref, i))
     def _accumulate():
         a = a_ref[0] != 0.0  # (B, B) bool — shared f32 staging
         o_ref[...] = o_ref[...] | _or_of_and(f_ref[...], a)
 
 
-def _packed_level_kernel_u32(
-    firsts_ref, valids_ref, tids_ref, frows_ref, fcols_ref, orows_ref, ocols_ref,
-    f_ref, a_ref, o_ref, *, block_size,
-):
+def _packed_level_kernel_u32(step_ref, src_ref, dst_ref, f_ref, a_ref, o_ref, *, block_size):
     """The fully bitpacked inner step — packed frontier × packed tiles:
     both operands are uint32 words, the adjacency bit-plane unpacks to a
     bool mask in-register (:func:`_unpack_tile_bits`) and the product is
@@ -341,26 +411,22 @@ def _packed_level_kernel_u32(
     VPU."""
     i = pl.program_id(0)
 
-    @pl.when(firsts_ref[i] == 1)
+    @pl.when(_first(step_ref, i))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(valids_ref[i] == 1)
+    @pl.when(_valid(step_ref, i))
     def _accumulate():
-        a = _unpack_tile_bits(a_ref[0], block_size)  # (B, B) bool
+        a = _unpack_tile_bits(a_ref, block_size)  # (B, B) bool
         o_ref[...] = o_ref[...] | _or_of_and(f_ref[...], a)
 
 
 def packed_level_blocks(
     frontier: jax.Array,  # (n_rows * q_pad, v_pad) uint32 lane words
     tiles: jax.Array,  # (n_tiles, B, B) f32 0/1 — the SAME Stage-A tensor
-    firsts: jax.Array,  # (n_steps,) int32 ∈ {0,1}
-    valids: jax.Array,  # (n_steps,) int32 ∈ {0,1}
-    tile_ids: jax.Array,  # (n_steps,) int32 into tiles
-    f_rows: jax.Array,  # (n_steps,) int32
-    f_cols: jax.Array,  # (n_steps,) int32
-    o_rows: jax.Array,  # (n_steps,) int32
-    o_cols: jax.Array,  # (n_steps,) int32
+    step: jax.Array,  # (n_steps,) int32 step words (pack_schedule)
+    src: jax.Array,  # (n_steps,) int32
+    dst: jax.Array,  # (n_steps,) int32
     block_size: int,
     q_pad: int,
     interpret: bool | None = None,
@@ -370,46 +436,17 @@ def packed_level_blocks(
     pallas_call — :func:`fused_level_blocks` with uint32 query-lane
     words instead of f32 rows (32× the lane density per row).
 
-    Takes the SAME host-built schedule (``firsts``/``valids``/id arrays
-    from ``ops.build_level_schedule``) and either tile store: the staged
+    Takes the SAME host-built step words (``ops.build_level_schedule``) and either tile store: the staged
     f32 tensor (thresholded to bool in-kernel) or the bitpacked uint32
     store (unpacked from bit-planes in-kernel — the packed×packed step
     is pure bitwise AND/OR, no f32 anywhere).  Returns the
     OR-accumulated word matrix (n_out_rows, v_pad) uint32 — already
     boolean per bit, no thresholding needed.
     """
-    n_rows, v_pad = frontier.shape
-    if n_out_rows is None:
-        n_out_rows = n_rows
-    n_steps = tile_ids.shape[0]
-    packed_tiles = tiles.dtype == jnp.uint32
     kernel = (
         partial(_packed_level_kernel_u32, block_size=block_size)
-        if packed_tiles
+        if tiles.dtype == jnp.uint32
         else _packed_level_kernel
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec(
-                (q_pad, block_size),
-                lambda i, fi, vl, ti, fr, fc, orw, oc: (fr[i], fc[i]),
-            ),
-            pl.BlockSpec(
-                (1, block_size, int(tiles.shape[2])),
-                lambda i, fi, vl, ti, fr, fc, orw, oc: (ti[i], 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (q_pad, block_size),
-            lambda i, fi, vl, ti, fr, fc, orw, oc: (orw[i], oc[i]),
-        ),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        name="rpq_packed_level",
-        out_shape=jax.ShapeDtypeStruct((n_out_rows, v_pad), jnp.uint32),
-        interpret=resolve_interpret(interpret),
-    )(firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols, frontier, tiles)
+    return _level_call(kernel, "rpq_packed_level", jnp.uint32, frontier, tiles, step, src, dst,
+                       block_size, q_pad, interpret, n_out_rows)

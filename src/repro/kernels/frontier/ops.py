@@ -82,14 +82,16 @@ from repro.graph.structure import LabeledGraph
 from repro.kernels.frontier.frontier import (
     frontier_step_blocks,
     fused_level_blocks,
+    pack_schedule,
     packed_level_blocks,
     resolve_interpret,
+    tile_block_shape,
 )
 from repro.kernels.frontier.ref import (
     TILE_DTYPES,
     pack_blocks,
     pack_blocks_chunked,
-    tile_words,
+    tile_shape,
 )
 
 # f32 sublane minimum: the row-tile rows one query would waste, used to
@@ -126,6 +128,13 @@ def shape_class(n: int, floor: int = BUCKET_FLOOR) -> int:
     """The power-of-two shape bucket ``n`` rounds up into (≥ ``floor``)."""
     n = max(int(n), 1)
     return max(floor, 1 << (n - 1).bit_length())
+
+
+def device_bytes(array) -> int:
+    """Bytes a device array takes as allocated, in the device's own
+    layout (tile padding included); a host array's ``nbytes``."""
+    size = getattr(array, "on_device_size_in_bytes", None)
+    return int(size()) if size is not None else int(np.asarray(array).nbytes)
 
 
 @dataclasses.dataclass
@@ -175,26 +184,39 @@ class StagedGraph:
     n_nodes: int
     v_pad: int
     block_size: int
-    tiles: jnp.ndarray  # (1 + sum nnz, B, B) f32; index 0 = zero cover tile
+    tiles: jnp.ndarray  # (1 + sum nnz, *tile_shape); index 0 = zero cover tile
     offsets: dict[tuple[int, int], tuple[int, np.ndarray, np.ndarray]]
     # total edge-list slices consumed by chunked Stage-A packing (0 when
     # the one-shot path packed every label store in one pass)
     staging_chunks: int = 0
-    # "f32" (dense 0/1 tiles, every semiring) or "uint32" (dst axis
-    # bitpacked into ceil(B/32) word planes — boolean semiring only, at
-    # 1/32 the staged bytes); see ``ref.pack_blocks``'s tile_dtype path
+    # "f32" (dense 0/1 tiles, every semiring) or "uint32" (src axis
+    # bitpacked into ceil(B/32) lane-dense word rows — boolean semiring
+    # only, at 1/32 the staged bytes); see ``ref.pack_blocks``'s
+    # tile_dtype path
     tile_dtype: str = "f32"
 
     @property
+    def n_tiles(self) -> int:
+        """Staged tiles, the zero cover tile included."""
+        return int(self.tiles.shape[0])
+
+    @property
     def tile_store_bytes(self) -> int:
-        """Total staged tile-tensor bytes (cover tile included)."""
-        return int(np.asarray(self.tiles).nbytes)
+        """Total staged tile-tensor bytes (cover tile included): the raw
+        bytes, tiles times one tile's bytes."""
+        return int(self.tiles.nbytes)
+
+    @property
+    def store_bytes(self) -> int:
+        """Device bytes of the staged tile tensor as allocated, layout
+        padding included (:func:`device_bytes`)."""
+        return device_bytes(self.tiles)
 
     def slab_bytes(self) -> dict[tuple[int, int], int]:
         """Per-(direction, label) staged bytes — each slab's tile count
         times the per-tile footprint of this store's dtype.  Derived
         from the offset tables, so it costs nothing to carry."""
-        per_tile = self.tile_store_bytes // max(int(self.tiles.shape[0]), 1)
+        per_tile = self.tile_store_bytes // max(self.n_tiles, 1)
         return {k: len(rows) * per_tile for k, (_, rows, _) in self.offsets.items()}
 
 
@@ -304,11 +326,8 @@ def _concat_stores(
     """Concatenate label stores behind the zero cover tile (index 0) and
     record each store's base offset + block coordinates — the staging
     layout shared by the global and per-site Stage-A builders."""
-    if tile_dtype == "uint32":
-        cover = np.zeros((1, block_size, tile_words(block_size)), np.uint32)
-    else:
-        cover = np.zeros((1, block_size, block_size), np.float32)
-    tile_arrays = [cover]
+    dtype = np.uint32 if tile_dtype == "uint32" else np.float32
+    tile_arrays = [np.zeros((1, *tile_shape(block_size, tile_dtype)), dtype)]
     offsets: dict[tuple[int, int], tuple[int, np.ndarray, np.ndarray]] = {}
     off = 1
     for key in sorted(stores):
@@ -428,7 +447,7 @@ class StagedShardedGraph:
     n_nodes: int
     v_pad: int
     block_size: int
-    site_tiles: tuple[np.ndarray, ...]  # per site: (n_tiles_s, B, B) f32
+    site_tiles: tuple[np.ndarray, ...]  # per site: (n_tiles_s, *tile_shape)
     site_offsets: tuple[dict[tuple[int, int], tuple[int, np.ndarray, np.ndarray]], ...]
     tile_dtype: str = "f32"  # see StagedGraph.tile_dtype
 
@@ -552,7 +571,7 @@ class TileBucket:
     n_tiles: int  # power-of-two padded per-site tile count
     slots: tuple[int, ...]  # local site indices (uniform across devices)
     sites: tuple[int, ...]  # global site ids, row-by-row (device-major)
-    tiles: jnp.ndarray  # (axis_size * len(slots), n_tiles, B, B) f32
+    tiles: jnp.ndarray  # (axis_size * len(slots), n_tiles, *tile_shape)
 
 
 @dataclasses.dataclass
@@ -628,9 +647,8 @@ def bucket_staged_sites(
         )
         if len(sites) == 1:  # nothing to unify: natural shape, no roundup
             cls = n_tiles[sites[0]]
-        width = b if staged.tile_dtype != "uint32" else tile_words(b)
-        dtype = np.float32 if staged.tile_dtype != "uint32" else np.uint32
-        stack = np.zeros((len(sites), cls, b, width), dtype)
+        dtype = np.uint32 if staged.tile_dtype == "uint32" else np.float32
+        stack = np.zeros((len(sites), cls, *tile_shape(b, staged.tile_dtype)), dtype)
         for row, s in enumerate(sites):
             stack[row, : n_tiles[s]] = staged.site_tiles[s]
         buckets.append(
@@ -766,12 +784,14 @@ class FusedLevelPlan:
     One grid step per (fan-in transition group, label, nonzero tile)
     triple, plus one zero-tile cover step per output block no real step
     writes (so every output block is initialized).  Steps are sorted by
-    (dst_state, block_col) — the output-revisiting order — ``firsts``
-    marks each output block's first step for the in-kernel zero-init,
-    and ``valids`` marks the steps that carry a real tile (cover steps
-    skip the tile product in-kernel).  ``union_members`` lists the fan-in
-    union rows the schedule's ``f_rows`` may address past ``n_states``;
-    callers extend the frontier with :func:`extend_frontier` first.
+    (dst_state, block_col) — the output-revisiting order — and packed
+    once, here on the host, into the three step words the kernel
+    prefetches: each step's ``first`` bit marks an output block's first
+    step for the in-kernel zero-init, and its ``valid`` bit marks the
+    steps that carry a real tile (cover steps skip the tile product
+    in-kernel).  ``union_members`` lists the fan-in union rows a step's
+    frontier row may address past ``n_states``; callers extend the
+    frontier with :func:`extend_frontier` first.
     """
 
     n_states: int
@@ -781,14 +801,13 @@ class FusedLevelPlan:
     q_pad: int
     n_real_steps: int  # grid steps carrying a real tile (excludes covers)
     union_members: tuple[tuple[int, ...], ...]
-    tiles: jnp.ndarray  # (n_tiles, B, B); index 0 is the all-zero cover tile
-    firsts: jnp.ndarray  # (n_steps,) int32 0/1
-    valids: jnp.ndarray  # (n_steps,) int32 0/1; 0 = cover step, dot skipped
-    tile_ids: jnp.ndarray  # (n_steps,) int32
-    f_rows: jnp.ndarray  # (n_steps,) int32: src state or union row
-    f_cols: jnp.ndarray  # (n_steps,) int32: tile block row
-    o_rows: jnp.ndarray  # (n_steps,) int32: dst automaton state
-    o_cols: jnp.ndarray  # (n_steps,) int32: tile block col
+    tiles: jnp.ndarray  # (n_tiles, *tile_shape); index 0 is the all-zero cover tile
+    # (n_steps,) int32 step words (frontier.pack_schedule): tile id,
+    # valid, first; frontier row (src state or union row) and block; output
+    # row (dst automaton state) and block
+    step: jnp.ndarray
+    src: jnp.ndarray
+    dst: jnp.ndarray
     # dtype of the aliased tile store ("f32" or "uint32") — the kernels
     # dispatch off the array dtype; the field gates the f32-only
     # semirings (witness levels, counting) at the wrapper layer
@@ -816,7 +835,8 @@ def level_kernel_bytes(
     block (``tile_block_bytes``) where ``tile_ids`` changes, the
     ``(q_pad, block_size)`` frontier block where ``(f_rows, f_cols)``
     changes, the output block of the same size where ``(o_rows, o_cols)``
-    changes, and the seven int32 scalar-prefetch arrays.  Frontier and
+    changes, and the three int32 scalar-prefetch step words
+    (``frontier.pack_schedule``).  Frontier and
     output elements are 4 bytes (f32 rows or uint32 lane words).  A
     cover step's zero tile counts like any other: the copy does not know
     that ``valids`` skips the product."""
@@ -836,7 +856,7 @@ def level_kernel_bytes(
         fetches(tile_ids) * tile_block_bytes
         + fetches(f_rows, f_cols) * row_block
         + fetches(o_rows, o_cols) * row_block
-        + 7 * 4 * n
+        + 3 * 4 * n
     )
 
 
@@ -910,8 +930,11 @@ def build_level_schedule(
     nb = staged.v_pad // staged.block_size
     frow_map, union_members = fanin_frontier_rows(ca)
     arr, firsts, valids, n_real = _schedule_steps(ca, staged.offsets, nb, frow_map)
+    step, src, dst = pack_schedule(
+        firsts, valids, arr[:, 4], arr[:, 2], arr[:, 3], arr[:, 0], arr[:, 1]
+    )
     tiles = staged.tiles
-    tile_block_bytes = staged.block_size * int(tiles.shape[2]) * tiles.dtype.itemsize
+    tile_block_bytes = int(np.prod(tile_block_shape(tiles))) * tiles.dtype.itemsize
     return FusedLevelPlan(
         n_states=ca.n_states,
         n_nodes=staged.n_nodes,
@@ -921,13 +944,9 @@ def build_level_schedule(
         n_real_steps=n_real,
         union_members=union_members,
         tiles=staged.tiles,
-        firsts=jnp.asarray(firsts),
-        valids=jnp.asarray(valids),
-        tile_ids=jnp.asarray(arr[:, 4]),
-        f_rows=jnp.asarray(arr[:, 2]),
-        f_cols=jnp.asarray(arr[:, 3]),
-        o_rows=jnp.asarray(arr[:, 0]),
-        o_cols=jnp.asarray(arr[:, 1]),
+        step=jnp.asarray(step),
+        src=jnp.asarray(src),
+        dst=jnp.asarray(dst),
         tile_dtype=staged.tile_dtype,
         kernel_bytes=level_kernel_bytes(
             arr[:, 4], arr[:, 2], arr[:, 3], arr[:, 0], arr[:, 1],
@@ -961,7 +980,7 @@ class PlanBucket:
     """One shape bucket of a :class:`ShardedLevelPlan`: the member
     sites' schedules stacked (shard_map row order, see
     :class:`TileBucket`) and padded to the bucket's power-of-two grid
-    length ``n_steps``.  Padding steps are ``firsts=0, valids=0``
+    length ``n_steps``.  Padding steps are ``first=0, valid=0``
     zero-tile references to the last output block: they keep the
     (o_row, o_col) sort order, hit a block every schedule has already
     initialized, and early-out in-kernel — a predicate, not a tile pass.
@@ -971,14 +990,10 @@ class PlanBucket:
     n_tiles: int  # power-of-two padded per-site tile count (shape class)
     slots: tuple[int, ...]  # local site indices in this bucket
     sites: tuple[int, ...]  # global site ids, row-by-row (device-major)
-    tiles: jnp.ndarray  # (axis_size * len(slots), n_tiles, B, B)
-    firsts: jnp.ndarray  # (rows, n_steps) int32 0/1
-    valids: jnp.ndarray  # (rows, n_steps) int32 0/1
-    tile_ids: jnp.ndarray  # (rows, n_steps) int32
-    f_rows: jnp.ndarray  # (rows, n_steps) int32
-    f_cols: jnp.ndarray  # (rows, n_steps) int32
-    o_rows: jnp.ndarray  # (rows, n_steps) int32
-    o_cols: jnp.ndarray  # (rows, n_steps) int32
+    tiles: jnp.ndarray  # (axis_size * len(slots), n_tiles, *tile_shape)
+    step: jnp.ndarray  # (rows, n_steps) int32 step words (frontier.pack_schedule)
+    src: jnp.ndarray  # (rows, n_steps) int32
+    dst: jnp.ndarray  # (rows, n_steps) int32
 
 
 @dataclasses.dataclass
@@ -1074,16 +1089,19 @@ def build_sharded_level_schedule(
             else max_len
         )
         padded += n_steps * len(tb.sites)
-        cols = {k: [] for k in ("fi", "vl", "ti", "fr", "fc", "orw", "oc")}
+        words = []
         for s in tb.sites:
             arr, fi, vl, _ = site_steps[s]
-            cols["fi"].append(pad_steps(fi, n_steps, 0))
-            cols["vl"].append(pad_steps(vl, n_steps, 0))
-            cols["ti"].append(pad_steps(arr[:, 4], n_steps, 0))  # zero cover tile
-            cols["fr"].append(pad_steps(arr[:, 2], n_steps, 0))
-            cols["fc"].append(pad_steps(arr[:, 3], n_steps, 0))
-            cols["orw"].append(pad_steps(arr[:, 0], n_steps, ca.n_states - 1))
-            cols["oc"].append(pad_steps(arr[:, 1], n_steps, nb - 1))
+            words.append(pack_schedule(
+                pad_steps(fi, n_steps, 0),
+                pad_steps(vl, n_steps, 0),
+                pad_steps(arr[:, 4], n_steps, 0),  # zero cover tile
+                pad_steps(arr[:, 2], n_steps, 0),
+                pad_steps(arr[:, 3], n_steps, 0),
+                pad_steps(arr[:, 0], n_steps, ca.n_states - 1),
+                pad_steps(arr[:, 1], n_steps, nb - 1),
+            ))
+        step, src, dst = (jnp.asarray(np.stack(w)) for w in zip(*words))
         buckets.append(
             PlanBucket(
                 n_steps=n_steps,
@@ -1091,13 +1109,9 @@ def build_sharded_level_schedule(
                 slots=tb.slots,
                 sites=tb.sites,
                 tiles=tb.tiles,
-                firsts=jnp.asarray(np.stack(cols["fi"])),
-                valids=jnp.asarray(np.stack(cols["vl"])),
-                tile_ids=jnp.asarray(np.stack(cols["ti"])),
-                f_rows=jnp.asarray(np.stack(cols["fr"])),
-                f_cols=jnp.asarray(np.stack(cols["fc"])),
-                o_rows=jnp.asarray(np.stack(cols["orw"])),
-                o_cols=jnp.asarray(np.stack(cols["oc"])),
+                step=step,
+                src=src,
+                dst=dst,
             )
         )
     return ShardedLevelPlan(
@@ -1147,12 +1161,12 @@ def build_sharded_level_plan(
     ),
 )
 def _fused_expand(
-    frontier, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier, tiles, step, src, dst,
     *, block_size, q_pad, interpret, union_members, n_states,
 ):
     fre = extend_frontier(frontier, union_members, n_states, q_pad)
     counts = fused_level_blocks(
-        fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+        fre, tiles, step, src, dst,
         block_size, q_pad, interpret=interpret,
         n_out_rows=n_states * q_pad,
     )
@@ -1166,8 +1180,7 @@ def expand_level_fused(
 ) -> jnp.ndarray:
     """One BFS level over all grounded transitions — ONE pallas_call."""
     return _fused_expand(
-        frontier, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad,
         interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
@@ -1181,7 +1194,7 @@ def expand_level_fused(
     ),
 )
 def _reach_fixpoint(
-    frontier0, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier0, tiles, step, src, dst,
     *, block_size, q_pad, max_levels, interpret, union_members, n_states,
 ):
     """Device-resident BFS fixpoint: lax.while_loop over fused levels.
@@ -1198,7 +1211,7 @@ def _reach_fixpoint(
         visited, frontier, lev = state
         fre = extend_frontier(frontier, union_members, n_states, q_pad)
         counts = fused_level_blocks(
-            fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+            fre, tiles, step, src, dst,
             block_size, q_pad, interpret=interpret,
             n_out_rows=n_states * q_pad,
         )
@@ -1220,8 +1233,7 @@ def reach_fixpoint(
 ) -> jnp.ndarray:
     """Visited product states (same layout as ``frontier0``) at fixpoint."""
     return _reach_fixpoint(
-        frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier0, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad,
         max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
@@ -1235,7 +1247,7 @@ def reach_fixpoint(
     ),
 )
 def _reach_fixpoint_levels(
-    frontier0, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier0, tiles, step, src, dst,
     *, block_size, q_pad, max_levels, interpret, union_members, n_states,
 ):
     """:func:`_reach_fixpoint` with the witness carry: alongside the
@@ -1254,7 +1266,7 @@ def _reach_fixpoint_levels(
         visited, frontier, lev, levels = state
         fre = extend_frontier(frontier, union_members, n_states, q_pad)
         counts = fused_level_blocks(
-            fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+            fre, tiles, step, src, dst,
             block_size, q_pad, interpret=interpret,
             n_out_rows=n_states * q_pad,
         )
@@ -1295,8 +1307,7 @@ def reach_fixpoint_levels(
     Refuses a ``tile_dtype="uint32"`` plan (boolean-only store)."""
     _require_f32_tiles(plan, "reach_fixpoint_levels")
     return _reach_fixpoint_levels(
-        frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier0, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad,
         max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
@@ -1311,7 +1322,7 @@ def reach_fixpoint_levels(
     ),
 )
 def _count_paths_bounded(
-    frontier0, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier0, tiles, step, src, dst,
     *, block_size, q_pad, n_levels, interpret, union_members, n_states, accepting,
 ):
     acc_rows = jnp.asarray(accepting, jnp.int32)
@@ -1323,7 +1334,7 @@ def _count_paths_bounded(
         counts, total = state
         fre = extend_frontier_sum(counts, union_members, n_states, q_pad)
         nxt = fused_level_blocks(
-            fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+            fre, tiles, step, src, dst,
             block_size, q_pad, interpret=interpret,
             n_out_rows=n_states * q_pad,
         )
@@ -1362,8 +1373,7 @@ def count_paths_bounded(
     (the counting semiring is contracted to the f32 store)."""
     _require_f32_tiles(plan, "count_paths_bounded")
     return _count_paths_bounded(
-        frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier0, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad, n_levels=n_levels,
         interpret=resolve_interpret(interpret), union_members=plan.union_members,
         n_states=plan.n_states, accepting=tuple(accepting),
@@ -1485,12 +1495,12 @@ def stack_start_masks_packed(
     ),
 )
 def _packed_expand(
-    frontier, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier, tiles, step, src, dst,
     *, block_size, q_pad, interpret, union_members, n_states,
 ):
     fre = extend_frontier_packed(frontier, union_members, n_states, q_pad)
     return packed_level_blocks(
-        fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+        fre, tiles, step, src, dst,
         block_size, q_pad, interpret=interpret,
         n_out_rows=n_states * q_pad,
     )
@@ -1505,8 +1515,7 @@ def expand_level_packed(
     pallas_call on the SAME Stage-B plan the f32 path uses (the staged
     f32 tiles are thresholded to bool in-kernel)."""
     return _packed_expand(
-        frontier, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad,
         interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
@@ -1520,7 +1529,7 @@ def expand_level_packed(
     ),
 )
 def _reach_fixpoint_packed(
-    frontier0, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier0, tiles, step, src, dst,
     *, block_size, q_pad, max_levels, interpret, union_members, n_states,
 ):
     """Device-resident packed BFS fixpoint: lax.while_loop over packed
@@ -1536,7 +1545,7 @@ def _reach_fixpoint_packed(
         visited, frontier, lev = state
         fre = extend_frontier_packed(frontier, union_members, n_states, q_pad)
         nxt = packed_level_blocks(
-            fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+            fre, tiles, step, src, dst,
             block_size, q_pad, interpret=interpret,
             n_out_rows=n_states * q_pad,
         )
@@ -1557,8 +1566,7 @@ def reach_fixpoint_packed(
 ) -> jnp.ndarray:
     """Visited lane words (same layout as ``frontier0``) at fixpoint."""
     return _reach_fixpoint_packed(
-        frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier0, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad,
         max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,
@@ -1572,7 +1580,7 @@ def reach_fixpoint_packed(
     ),
 )
 def _reach_fixpoint_packed_levels(
-    frontier0, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+    frontier0, tiles, step, src, dst,
     *, block_size, q_pad, max_levels, interpret, union_members, n_states,
 ):
     """:func:`_reach_fixpoint_packed` with the witness carry.  The
@@ -1591,7 +1599,7 @@ def _reach_fixpoint_packed_levels(
         visited, frontier, lev, levels = state
         fre = extend_frontier_packed(frontier, union_members, n_states, q_pad)
         nxt = packed_level_blocks(
-            fre, tiles, firsts, valids, tids, frows, fcols, orows, ocols,
+            fre, tiles, step, src, dst,
             block_size, q_pad, interpret=interpret,
             n_out_rows=n_states * q_pad,
         )
@@ -1631,8 +1639,7 @@ def reach_fixpoint_packed_levels(
     (witness levels are contracted to the f32 store)."""
     _require_f32_tiles(plan, "reach_fixpoint_packed_levels")
     return _reach_fixpoint_packed_levels(
-        frontier0, plan.tiles, plan.firsts, plan.valids, plan.tile_ids,
-        plan.f_rows, plan.f_cols, plan.o_rows, plan.o_cols,
+        frontier0, plan.tiles, plan.step, plan.src, plan.dst,
         block_size=plan.block_size, q_pad=plan.q_pad,
         max_levels=max_levels, interpret=resolve_interpret(interpret),
         union_members=plan.union_members, n_states=plan.n_states,

@@ -9,55 +9,64 @@ import jax.numpy as jnp
 
 # Tile-store dtypes a Stage-A staging may carry.  ``"f32"`` is the dense
 # 0/1 tensor every semiring can run (boolean, witness levels, counting);
-# ``"uint32"`` packs the dst axis into bit-planes — ``tile_words(B)``
-# uint32 words per row, dst ``d`` at word ``d // 32`` bit ``d % 32``,
-# mirroring the frontier's ``pack_lane_masks`` layout — a 32× smaller
-# store that only the boolean semiring can consume.
+# ``"uint32"`` packs the src axis into bit-planes — a tile is
+# ``tile_words(B)`` rows of ``B`` uint32 lanes, edge ``(v, j)`` at word
+# row ``v // 32``, lane ``j``, bit ``v % 32`` — a 32× smaller store that
+# only the boolean semiring can consume.  The dst axis runs along the
+# lanes, so the store is lane-dense: the level kernel's (1, W, B) tile
+# block is the array's own layout and needs no relayout copy.
 TILE_DTYPES = ("f32", "uint32")
 
 
 def tile_words(block_size: int) -> int:
-    """uint32 words per tile row at ``tile_dtype="uint32"``."""
+    """uint32 word rows per tile at ``tile_dtype="uint32"``."""
     return -(-block_size // 32)
 
 
+def tile_shape(block_size: int, tile_dtype: str) -> tuple[int, int]:
+    """One tile's shape in a store of ``tile_dtype``: (B, B) f32 rows, or
+    (tile_words(B), B) uint32 bit-plane rows with the dst along lanes."""
+    if tile_dtype not in TILE_DTYPES:
+        raise ValueError(f"tile_dtype must be one of {TILE_DTYPES}, got {tile_dtype!r}")
+    if tile_dtype == "uint32":
+        return tile_words(block_size), block_size
+    return block_size, block_size
+
+
 def unpack_tiles(tiles: np.ndarray, block_size: int) -> np.ndarray:
-    """Expand a bitpacked (nnz, B, W) uint32 tile tensor back to the
+    """Expand a bitpacked (nnz, W, B) uint32 tile tensor back to the
     dense (nnz, B, B) f32 0/1 form — the inverse of the ``"uint32"``
     packing path, used by oracles and byte-identity tests.  A f32 tensor
     passes through unchanged."""
     tiles = np.asarray(tiles)
     if tiles.dtype != np.uint32:
         return tiles
-    nnz, b, w = tiles.shape
+    nnz, w, b = tiles.shape
     shifts = np.arange(32, dtype=np.uint32)
-    bits = (tiles[:, :, :, None] >> shifts) & np.uint32(1)
-    return bits.reshape(nnz, b, w * 32)[:, :, :block_size].astype(np.float32)
+    bits = (tiles[:, :, None, :] >> shifts[None, None, :, None]) & np.uint32(1)
+    return bits.reshape(nnz, w * 32, b)[:, :block_size, :].astype(np.float32)
 
 
 def _scatter_edges(
     tiles: np.ndarray, idx: np.ndarray, s: np.ndarray, d: np.ndarray, block_size: int
 ) -> None:
     """Scatter one edge slice into the tile tensor (dtype-dispatched):
-    f32 tiles set the (src, dst) cell to 1, uint32 tiles OR the dst bit
-    into its word plane (``bitwise_or.at`` — duplicate edges must not
-    drop bits the way a fancy-indexed assignment would)."""
+    f32 tiles set the (src, dst) cell to 1, uint32 tiles OR the src bit
+    into its word row at the dst lane (``bitwise_or.at`` — duplicate
+    edges must not drop bits the way a fancy-indexed assignment would)."""
     if tiles.dtype == np.uint32:
         np.bitwise_or.at(
             tiles,
-            (idx, s % block_size, (d % block_size) // 32),
-            np.uint32(1) << ((d % block_size) % 32).astype(np.uint32),
+            (idx, (s % block_size) // 32, d % block_size),
+            np.uint32(1) << ((s % block_size) % 32).astype(np.uint32),
         )
     else:
         tiles[idx, s % block_size, d % block_size] = 1.0
 
 
 def _alloc_tiles(nnz: int, block_size: int, tile_dtype: str) -> np.ndarray:
-    if tile_dtype not in TILE_DTYPES:
-        raise ValueError(f"tile_dtype must be one of {TILE_DTYPES}, got {tile_dtype!r}")
-    if tile_dtype == "uint32":
-        return np.zeros((max(nnz, 1), block_size, tile_words(block_size)), np.uint32)
-    return np.zeros((max(nnz, 1), block_size, block_size), np.float32)
+    dtype = np.uint32 if tile_dtype == "uint32" else np.float32
+    return np.zeros((max(nnz, 1), *tile_shape(block_size, tile_dtype)), dtype)
 
 
 def pack_blocks(
@@ -70,11 +79,12 @@ def pack_blocks(
     """Pack one label's edge list into dense B×B tiles (block-sparse).
 
     Returns (tiles (nnz,B,B) f32, block_rows, block_cols sorted by col) and
-    the padded node count.  ``tile_dtype="uint32"`` packs the dst axis
-    into bit-planes instead — tiles become (nnz, B, ceil(B/32)) uint32
-    with dst ``d`` at word ``d // 32`` bit ``d % 32`` — the same block
-    layout (rows/cols/order byte-identical to the f32 path) at 1/32 the
-    bytes; :func:`unpack_tiles` recovers the dense form exactly."""
+    the padded node count.  ``tile_dtype="uint32"`` packs the src axis
+    into bit-planes instead — tiles become (nnz, ceil(B/32), B) uint32
+    with edge ``(v, j)`` at word row ``v // 32``, lane ``j``, bit
+    ``v % 32`` — the same block layout (rows/cols/order byte-identical to
+    the f32 path) at 1/32 the bytes; :func:`unpack_tiles` recovers the
+    dense form exactly."""
     v_pad = -(-n_nodes // block_size) * block_size
     br = src // block_size
     bc = dst // block_size

@@ -47,8 +47,12 @@ from repro.core.plans import GraphPlanStore
 from repro.graph.partition import Placement
 from repro.graph.structure import LabeledGraph
 from repro.kernels.frontier import ops as fops
+from repro.kernels.frontier.ref import tile_shape
 
-FORMAT_VERSION = 1
+# 2: the uint32 store's tiles are (ceil(B/32), B) lane-dense bit-plane
+# rows (src bits, dst lanes); a version-1 snapshot holds (B, ceil(B/32))
+# dst-bit tiles, which would read as a different graph, so it rebuilds
+FORMAT_VERSION = 2
 
 # the pack_blocks products; everything else in the store derives from
 # these (or from the raw placement) without packing a single tile
@@ -113,24 +117,34 @@ def _encode(kind: str, artifact: Any) -> dict:
     raise ValueError(f"unpersistable Stage-A kind {kind!r}")
 
 
+def _check_tiles(tiles: np.ndarray, block_size: int, tile_dtype: str) -> np.ndarray:
+    """A payload's tiles, refused (``ValueError``) unless each tile has
+    the shape of ``tile_dtype``'s layout at ``block_size``."""
+    tiles = np.asarray(tiles)
+    if tiles.ndim != 3 or tiles.shape[1:] != tile_shape(block_size, tile_dtype):
+        raise ValueError(f"tiles {tiles.shape} are not the {tile_dtype} layout at B={block_size}")
+    return tiles
+
+
 def _decode(kind: str, payload: dict) -> Any:
-    # tile_dtype was added with the bitpacked store; snapshots written
-    # before it carry (implicitly f32) dense tiles
+    dtype = payload["tile_dtype"]
     if kind == "staged_graph":
         return fops.StagedGraph(
             n_nodes=payload["n_nodes"], v_pad=payload["v_pad"],
             block_size=payload["block_size"],
-            tiles=jnp.asarray(payload["tiles"]),
+            tiles=jnp.asarray(_check_tiles(payload["tiles"], payload["block_size"], dtype)),
             offsets=dict(payload["offsets"]),
-            tile_dtype=payload.get("tile_dtype", "f32"),
+            tile_dtype=dtype,
         )
     if kind == "staged_sharded":
         return fops.StagedShardedGraph(
             n_sites=payload["n_sites"], n_nodes=payload["n_nodes"],
             v_pad=payload["v_pad"], block_size=payload["block_size"],
-            site_tiles=tuple(np.asarray(t) for t in payload["site_tiles"]),
+            site_tiles=tuple(
+                _check_tiles(t, payload["block_size"], dtype) for t in payload["site_tiles"]
+            ),
             site_offsets=tuple(dict(o) for o in payload["site_offsets"]),
-            tile_dtype=payload.get("tile_dtype", "f32"),
+            tile_dtype=dtype,
         )
     raise ValueError(f"unpersistable Stage-A kind {kind!r}")
 

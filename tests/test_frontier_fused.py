@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from repro.core import paa
 from repro.graph.generators import random_labeled_graph
 from repro.graph.structure import LabeledGraph, example_graph, to_device_graph
-from repro.kernels.frontier.frontier import count_pallas_calls
+from repro.kernels.frontier.frontier import count_pallas_calls, unpack_schedule
 from repro.kernels.frontier.ops import (
     QPAD,
     build_level_plan,
@@ -176,9 +176,8 @@ def test_plan_covers_every_output_block():
     ca = paa.compile_query("l0 l2* (l1|l3)^-1", g)
     plan = build_level_plan(ca, bg)
     nb = plan.v_pad // plan.block_size
-    orows = np.asarray(plan.o_rows)
-    ocols = np.asarray(plan.o_cols)
-    firsts = np.asarray(plan.firsts)
+    c = unpack_schedule(plan.step, plan.src, plan.dst)
+    orows, ocols, firsts = c["o_rows"], c["o_cols"], c["firsts"]
     blocks = set(zip(orows.tolist(), ocols.tolist()))
     assert blocks == {(s, c) for s in range(ca.n_states) for c in range(nb)}
     # sorted by (o_row, o_col); firsts flags each block's first step only

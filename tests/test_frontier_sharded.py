@@ -18,6 +18,7 @@ from repro.dist import compat
 from repro.graph.generators import random_labeled_graph
 from repro.graph.partition import Placement, distribute
 from repro.graph.structure import to_device_graph
+from repro.kernels.frontier.frontier import unpack_schedule
 from repro.kernels.frontier.ops import build_sharded_level_plan
 
 from tests.test_multidevice import CHILD_ENV, SUBPROCESS_TIMEOUT_S
@@ -130,7 +131,7 @@ def test_sharded_plan_bucket_invariants(setup):
     classes of multi-member buckets are powers of two (a singleton
     bucket has nothing to unify and keeps its natural shape), every
     site's useful steps fit its bucket, padding steps are
-    valids=0/firsts=0 zero-tile no-ops on the last output block, and
+    valid=0/first=0 zero-tile no-ops on the last output block, and
     each site's real prefix still covers every (dst_state, block_col)
     block."""
     g, _, _, _ = setup
@@ -155,12 +156,12 @@ def test_sharded_plan_bucket_invariants(setup):
         if len(b.sites) > 1:  # shared program: power-of-two classes
             assert b.n_steps & (b.n_steps - 1) == 0
             assert b.n_tiles & (b.n_tiles - 1) == 0
-        assert b.firsts.shape == (len(b.sites), b.n_steps)
+        assert b.step.shape == b.src.shape == b.dst.shape == (len(b.sites), b.n_steps)
         assert b.tiles.shape[:2] == (len(b.sites), b.n_tiles)
         assert (np.asarray(b.tiles)[:, 0] == 0).all()  # zero cover tile
-        orows, ocols = np.asarray(b.o_rows), np.asarray(b.o_cols)
-        tids, firsts = np.asarray(b.tile_ids), np.asarray(b.firsts)
-        valids = np.asarray(b.valids)
+        c = unpack_schedule(b.step, b.src, b.dst)
+        orows, ocols, tids, firsts, valids = (
+            c[k] for k in ("o_rows", "o_cols", "tile_ids", "firsts", "valids"))
         for row, s in enumerate(b.sites):
             seen_sites.append(s)
             key = orows[row].astype(np.int64) * nb + ocols[row]
@@ -170,7 +171,7 @@ def test_sharded_plan_bucket_invariants(setup):
             assert firsts[row].sum() == ca.n_states * nb, s
             # the site's own (unpadded) schedule fits its bucket; the
             # padding tail multiplies the zero cover tile into the last
-            # output block with firsts=0 AND valids=0 (in-kernel skip)
+            # output block with first=0 AND valid=0 (in-kernel skip)
             own_plan = build_sharded_level_plan(ca, [site_graphs[s]], block_size=8)
             own_len = int(own_plan.useful_steps)
             assert own_len <= b.n_steps, s

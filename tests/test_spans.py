@@ -160,7 +160,7 @@ def test_s2_flush_span_tree(placement, recording):
     names = by_name(recs)
     assert set(names) == {
         "flush", "plan", "plan.estimate", "plan.compile", "plan.decide",
-        "s2.executor", "s2.call", "s2.dispatch", "s2.fetch", "s2.rows",
+        "s2.executor", "s2.stage", "s2.call", "s2.dispatch", "s2.fetch", "s2.rows",
         "s2.answers", "s2.calibrate", "s2.finish",
     }
     (fl,) = names["flush"]
@@ -178,6 +178,14 @@ def test_s2_flush_span_tree(placement, recording):
     assert [r.counters["hit"] for r in names["plan"]] == [0, 0]
     assert all(r.counters["rollouts"] == 30 for r in names["plan.estimate"])
     assert sorted(r.counters["built"] for r in names["s2.executor"]) == [1, 1]
+    # each build fetches Stage A inside its executor span: the service's
+    # default f32 store, 8 x 8 f32 a tile at block_size 8
+    stages = names["s2.stage"]
+    assert len(stages) == 2 and all(ids[r.parent].name == "s2.executor" for r in stages)
+    for r in stages:
+        assert r.counters["tiles"] > 1
+        assert r.counters["raw_bytes"] == r.counters["tiles"] * 8 * 8 * 4
+        assert r.counters["store_bytes"] >= r.counters["raw_bytes"]
     for name in ("s2.dispatch", "s2.fetch"):
         assert all(ids[r.parent].name == "s2.call" for r in names[name])
     # two signatures, one executor call each; the packed executor pads
@@ -187,6 +195,7 @@ def test_s2_flush_span_tree(placement, recording):
     for r in names["s2.fetch"]:
         assert r.counters["answer_bytes"] == 256 * placement.graph.n_nodes  # bool rows
         assert r.counters["levels"] >= 1 and r.counters["kernel_bytes"] > 0
+        assert r.counters["store_bytes"] == stages[0].counters["store_bytes"]
     for name in ("s2.answers", "s2.calibrate", "s2.finish"):
         assert sorted(r.request for r in names[name]) == [t1.id, t2.id]
         assert all(r.parent == fl.id for r in names[name])

@@ -79,7 +79,7 @@ def test_pack_blocks_uint32_is_bit_identical_at_1_32_bytes(block):
     assert vp_f == vp_u
     np.testing.assert_array_equal(rf, ru)
     np.testing.assert_array_equal(cf, cu)
-    assert tu.dtype == np.uint32 and tu.shape == (tf.shape[0], block, tile_words(block))
+    assert tu.dtype == np.uint32 and tu.shape == (tf.shape[0], tile_words(block), block)
     np.testing.assert_array_equal(unpack_tiles(tu, block), tf)
     assert tf.nbytes == 32 * tu.nbytes  # 32 | block for every swept size
 

@@ -14,6 +14,7 @@ written for a described device cannot be read back here.
 """
 
 import os
+import re
 
 import pytest
 
@@ -23,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.frontier import ops
 from repro.kernels.frontier.frontier import fused_level_blocks, packed_level_blocks
-from repro.kernels.frontier.ref import tile_words
+from repro.kernels.frontier.ref import tile_shape
 
 B = 128
 Q_PAD = ops.QPAD
@@ -57,18 +58,25 @@ def _sds(shape, dtype, sharding):
 
 
 def _tiles(tile_dtype, sharding, lead=()):
-    width = B if tile_dtype == "f32" else tile_words(B)
     dtype = jnp.float32 if tile_dtype == "f32" else jnp.uint32
-    return _sds((*lead, N_TILES, B, width), dtype, sharding)
+    return _sds((*lead, N_TILES, *tile_shape(B, tile_dtype)), dtype, sharding)
 
 
 def _schedule(sharding, lead=()):
-    """firsts, valids, tile_ids, f_rows, f_cols, o_rows, o_cols."""
-    return tuple(_sds((*lead, N_STEPS), jnp.int32, sharding) for _ in range(7))
+    """The step words: step, src, dst."""
+    return tuple(_sds((*lead, N_STEPS), jnp.int32, sharding) for _ in range(3))
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, tiles=None):
+    """The program holds the Pallas kernel and, given the tile store's
+    shape, no copy of the store: the kernel's tile block is the store's
+    own layout, so XLA has nothing to relayout."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if tiles is not None:
+        dims = ",".join(str(d) for d in tiles.shape)
+        copies = re.findall(rf"= \w+\[{dims}\]\S* copy(?:-start)?\(", text)
+        assert not copies, copies
 
 
 LEVEL_KERNELS = {
@@ -89,12 +97,9 @@ def test_level_kernel_compiles_for_v5e(one_chip, kernel, tile_dtype):
         )
 
     frontier = _sds(((N_STATES + 1) * Q_PAD, V_PAD), frontier_dtype, one_chip)
-    compiled = (
-        jax.jit(step)
-        .lower(frontier, _tiles(tile_dtype, one_chip), *_schedule(one_chip))
-        .compile()
-    )
-    _assert_kernel(compiled)
+    tiles = _tiles(tile_dtype, one_chip)
+    compiled = jax.jit(step).lower(frontier, tiles, *_schedule(one_chip)).compile()
+    _assert_kernel(compiled, tiles)
 
 
 @pytest.mark.parametrize("tile_dtype", ["f32", "uint32"])
@@ -113,23 +118,53 @@ def test_vmapped_fused_kernel_compiles_for_v5e(one_chip, tile_dtype):
 
     frontier = _sds((N_STATES * Q_PAD, V_PAD), jnp.float32, one_chip)
     lead = (N_SITE_ROWS,)
-    compiled = (
-        jax.jit(step)
-        .lower(frontier, _tiles(tile_dtype, one_chip, lead), *_schedule(one_chip, lead))
-        .compile()
-    )
-    _assert_kernel(compiled)
+    tiles = _tiles(tile_dtype, one_chip, lead)
+    compiled = jax.jit(step).lower(frontier, tiles, *_schedule(one_chip, lead)).compile()
+    _assert_kernel(compiled, tiles)
 
 
 def test_reach_fixpoint_compiles_for_v5e(one_chip):
     """The device-resident fixpoint: a while loop over fused levels at
     the twin's v_pad, one union row, uint32 tiles."""
     frontier0 = _sds((N_STATES * Q_PAD, V_PAD), jnp.float32, one_chip)
+    tiles = _tiles("uint32", one_chip)
     compiled = ops._reach_fixpoint.lower(
-        frontier0, _tiles("uint32", one_chip), *_schedule(one_chip),
+        frontier0, tiles, *_schedule(one_chip),
         block_size=B, q_pad=Q_PAD, max_levels=64, interpret=False,
         union_members=((0, 1),), n_states=N_STATES,
     ).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert "while" in text
+    _assert_kernel(compiled, tiles)
+    assert "while" in compiled.as_text()
+
+
+def test_packed_fixpoint_reads_the_store_without_a_copy(one_chip):
+    """The packed fixpoint the serving path runs: uint32 lane words over
+    the uint32 store, with the store passed straight to the level kernel
+    inside the while loop.  The compiled program allocates no temporary
+    the size of the store."""
+    frontier0 = _sds((N_STATES * Q_PAD, V_PAD), jnp.uint32, one_chip)
+    tiles = _tiles("uint32", one_chip)
+    compiled = ops._reach_fixpoint_packed.lower(
+        frontier0, tiles, *_schedule(one_chip),
+        block_size=B, q_pad=Q_PAD, max_levels=64, interpret=False,
+        union_members=((0, 1),), n_states=N_STATES,
+    ).compile()
+    _assert_kernel(compiled, tiles)
+    store_bytes = N_TILES * tile_shape(B, "uint32")[0] * B * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < store_bytes
+
+
+def test_packed_fixpoint_holds_an_ldbc_size_schedule(one_chip):
+    """The schedule rides in SMEM (1 MiB) as scalar prefetch: at three
+    step words a step, a level of 80k grid steps (the LDBC SF0.1 cell's
+    largest is about 68k) over a 351k-tile store still compiles."""
+    n_steps, v_pad = 80_000, -(-322_527 // B) * B
+    frontier0 = _sds((N_STATES * Q_PAD, v_pad), jnp.uint32, one_chip)
+    tiles = _sds((351_100, *tile_shape(B, "uint32")), jnp.uint32, one_chip)
+    schedule = tuple(_sds((n_steps,), jnp.int32, one_chip) for _ in range(3))
+    compiled = ops._reach_fixpoint_packed.lower(
+        frontier0, tiles, *schedule,
+        block_size=B, q_pad=Q_PAD, max_levels=64, interpret=False,
+        union_members=((0, 1),), n_states=N_STATES,
+    ).compile()
+    _assert_kernel(compiled, tiles)
